@@ -77,9 +77,11 @@ def _bench_decode_long_context() -> None:
     """The PR 4 newly-unlocked path: seq-sharded (long-context) decode
     with step fusion vs the per-access path it was pinned to before.
 
-    Runs in a subprocess on 8 fake devices (this process must keep seeing
-    1 device — the dry-run contract); same-run medians plus jaxpr-level
-    launch/mask counts, all measured INSIDE the one child."""
+    Runs in a subprocess on 8 fake CPU devices (this process must keep
+    seeing 1 device — the dry-run contract); same-run medians plus
+    jaxpr-level launch/mask counts, all measured INSIDE the one child.
+    The child is pinned to the CPU backend, so it never claims the chip
+    this process may hold, and its row is labelled ``platform=cpu``."""
     import json
     import os
     import subprocess
@@ -88,6 +90,7 @@ def _bench_decode_long_context() -> None:
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + root
     cmd = [sys.executable,
            os.path.join(root, "benchmarks", "_bench_longctx.py")]
@@ -104,8 +107,9 @@ def _bench_decode_long_context() -> None:
          f"per_access_us={t_p:.1f} speedup={t_p / max(t_f, 1e-9):.2f}x "
          f"launches={rec['launches_fused']}vs{rec['launches_per_access']} "
          f"mask_ops={rec['mask_ops_fused']}vs{rec['mask_ops_per_access']} "
-         f"nshards={rec['nshards']} seq={rec['seq']} spmd_sim_bound=true",
-         speedup=round(t_p / max(t_f, 1e-9), 3), **rec)
+         f"nshards={rec['nshards']} seq={rec['seq']} spmd_sim_bound=true "
+         f"platform=cpu",
+         speedup=round(t_p / max(t_f, 1e-9), 3), platform="cpu", **rec)
 
 
 def _bench_pipeline() -> None:

@@ -54,6 +54,9 @@ def main() -> None:
                          "moe,step,serve")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
+
     from benchmarks import common
     common.QUICK = args.quick
 

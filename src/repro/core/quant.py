@@ -16,9 +16,7 @@ safe-divide in :func:`quantize` writes 0 (never NaN — fp8 HAS NaN
 encodings and a NaN page poisons every later gather), and dequant
 multiplies garbage ints by 0.
 
-fp8 is feature-gated: ``float8_e4m3fn`` when the installed jax exposes
-it, otherwise :func:`supported` returns False and callers must fall
-back or raise — nothing here imports optional packages.
+fp8 is ``float8_e4m3fn`` (``e5m2`` also accepted).
 """
 from __future__ import annotations
 
@@ -60,24 +58,9 @@ def canonical(name) -> str:
     return s
 
 
-def supported(name) -> bool:
-    """Whether this jax build can materialize the dtype (fp8 is gated)."""
-    try:
-        s = canonical(name)
-    except ValueError:
-        return False
-    return s == "int8" or hasattr(jnp, s)
-
-
 def pool_dtype(name):
     """jnp dtype object for a canonical/user-facing quantized dtype name."""
-    s = canonical(name)
-    if s == "int8":
-        return jnp.int8
-    if not hasattr(jnp, s):
-        raise ValueError(f"{s} unavailable in this jax build "
-                         f"(gate with quant.supported)")
-    return getattr(jnp, s)
+    return getattr(jnp, canonical(name))
 
 
 def qmax(dtype) -> float:

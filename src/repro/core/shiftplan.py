@@ -472,12 +472,8 @@ def permutation_plan(dest: tuple) -> ShiftPlan:
 # extra select operand.  Strategy selection weights by platform.
 @functools.lru_cache(maxsize=None)
 def _permute_penalty() -> int:
-    try:
-        import jax
-        platform = jax.devices()[0].platform
-    except Exception:  # pragma: no cover
-        platform = "cpu"
-    return 2 if platform == "tpu" else 6
+    import jax
+    return 2 if jax.devices()[0].platform == "tpu" else 6
 
 
 @_memoize("plan.segment_deint")

@@ -1,10 +1,9 @@
 """Distributed substrate: sharding contexts, spec builders, pipeline
-parallelism, and version-compat wrappers for the JAX SPMD APIs.
+parallelism.
 
 Modules
 -------
-sharding     : ShardCtx + PartitionSpec rules for params/activations/state,
-               plus `shard_map` / `make_mesh` compat shims.
+sharding     : ShardCtx + PartitionSpec rules for params/activations/state.
 pipeline_par : GPipe-style pipeline parallelism over a mesh axis.
 """
 from repro.dist import sharding  # noqa: F401

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.sharding import ShardCtx, shard_map
+from repro.dist.sharding import ShardCtx
 
 
 @dataclasses.dataclass(frozen=True)
@@ -108,7 +108,7 @@ def pipeline_loss_fn(params, batch, cfg, ctx: ShardCtx,
         loss = num / jnp.maximum(den, 1.0)
         return loss + aux, {"loss": loss, "aux": aux}
 
-    sm = shard_map(body, mesh=ctx.mesh,
-                   in_specs=(P(), P()), out_specs=(P(), P()),
-                   check_vma=False)
+    sm = jax.shard_map(body, mesh=ctx.mesh,
+                       in_specs=(P(), P()), out_specs=(P(), P()),
+                       check_vma=False)
     return sm(params, batch)

@@ -12,50 +12,16 @@ Axis roles
 Spec derivation is *rule-based on leaf path + shape* (not stored per-leaf),
 so checkpoints hold logical arrays and any mesh can rebuild placements
 (ft/elastic.py).
-
-This module also provides version-compat wrappers (``shard_map``,
-``make_mesh``) because the public JAX surface for these moved across the
-versions this repo must run on.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Sequence
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-
-# ---------------------------------------------------------------------------
-# Version-compat wrappers
-# ---------------------------------------------------------------------------
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` where available, else the experimental spelling
-    (mapping ``check_vma`` onto the older ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-        except TypeError:  # pre-check_vma signature
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types when the installed JAX
-    supports them, plain otherwise."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, TypeError):
-        return jax.make_mesh(tuple(shape), tuple(axes))
 
 
 # ---------------------------------------------------------------------------

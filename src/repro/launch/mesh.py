@@ -11,13 +11,22 @@ pipeline axis when pipeline parallelism is enabled (dist/pipeline_par.py).
 """
 from __future__ import annotations
 
-from repro.dist.sharding import ShardCtx, make_mesh
+import jax
+from jax.sharding import AxisType
+
+from repro.dist.sharding import ShardCtx
+
+
+def _auto_mesh(shape, axes):
+    """Mesh whose axes the compiler partitions (``AxisType.Auto``)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_ctx(mesh, *, long_context: bool = False,
@@ -45,4 +54,4 @@ def make_ctx(mesh, *, long_context: bool = False,
 
 def make_test_mesh(shape=(2, 4), axes=("data", "model")):
     """Small mesh over however many fake devices tests configured."""
-    return make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)

@@ -26,6 +26,7 @@ from repro.data.pipeline import DataConfig, SyntheticAoSPipeline
 from repro.dist.sharding import local_ctx
 from repro.ft.checkpoint import CheckpointManager
 from repro.ft.straggler import StragglerPolicy
+from repro.launch import compile_cache
 from repro.launch.mesh import make_ctx
 from repro.optim.adamw import AdamWConfig
 from repro.optim.compression import CompressionConfig
@@ -51,6 +52,7 @@ def main() -> None:
                     choices=["none", "single-pod", "multi-pod"],
                     help="production meshes need 256/512 devices (dry-run)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     arch = get_arch(args.arch)
     cfg = arch.smoke if args.smoke else arch.model

@@ -66,8 +66,8 @@ def qkv_project(params, x: jax.Array, n_heads: int, n_kv: int, head_dim: int,
     k, v = vx.transpose(vx.Segment(n=kv.shape[-1], fields=2), kv,
                         policy=policy)
     if params.get("q_norm") is not None:
-        q = layers.rms_norm(q, params["q_norm"])
-        k = layers.rms_norm(k, params["k_norm"])
+        q = layers.head_rms_norm(q, params["q_norm"])
+        k = layers.head_rms_norm(k, params["k_norm"])
     q = layers.rope(q, positions, rope_theta)
     k = layers.rope(k, positions, rope_theta)
     kv = vx.transpose(vx.Segment(n=kv.shape[-1], fields=2), [k, v],

@@ -15,6 +15,31 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     return ((x * jax.lax.rsqrt(var + eps)) * scale.astype(jnp.float32)).astype(dtype)
 
 
+def _pairwise_sum(x: jax.Array) -> jax.Array:
+    """Sum over the last axis, keepdims, by folding halves elementwise: the
+    order of the float additions is fixed by the program, not by the layout
+    XLA picks for ``x``."""
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        s = x[..., :h] + x[..., h:2 * h]
+        x = jnp.concatenate([s, x[..., 2 * h:]], -1) if x.shape[-1] % 2 else s
+    return x
+
+
+def head_rms_norm(x: jax.Array, scale: jax.Array,
+                  eps: float = 1e-6) -> jax.Array:
+    """``rms_norm`` over head_dim (the q/k norm) with a layout-independent
+    sum of squares.  A reduction's order follows its operand's layout, and
+    that layout depends on the consumer: K feeding a Mosaic interleave keeps
+    head_dim on lanes, K fused into XLA's interleave does not.  A fixed
+    order makes both lowerings of a prefill write the same bits."""
+    dtype = x.dtype
+    x = x.astype(jnp.float32)
+    var = _pairwise_sum(jnp.square(x)) / x.shape[-1]
+    return ((x * jax.lax.rsqrt(var + eps))
+            * scale.astype(jnp.float32)).astype(dtype)
+
+
 def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
                eps: float = 1e-5) -> jax.Array:
     dtype = x.dtype

@@ -161,11 +161,10 @@ def moe_layer(params, x: jax.Array, spec: MoESpec, ctx) -> tuple[jax.Array, jax.
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.sharding import shard_map
     ba = ctx.data_axes if ctx.data_axes else None
     bspec = P(ba, None, None)
     ospec = P(ba, ctx.model_axis if seq_scatter else None, None)
-    sm = shard_map(
+    sm = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(), P(ctx.model_axis), P(ctx.model_axis),
                   P(ctx.model_axis), bspec),

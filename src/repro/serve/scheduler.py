@@ -668,6 +668,16 @@ class Scheduler:
         self._slot_req[slot] = None
 
     # -- decode -------------------------------------------------------------
+    def compile_decode(self) -> jax.stages.Compiled:
+        """Lower and compile the plain decode step ahead of time, with the
+        signature ``step`` gives its jit, so its ``as_text()`` shows which
+        kernels that program launches.  The result is for inspection:
+        ``step`` dispatches through the jit and does not run it."""
+        tok = jnp.zeros((self.slots,), jnp.int32)
+        act = jnp.zeros((self.slots,), bool)
+        return self._step.lower(self.params, self.cache.state, tok,
+                                act).compile()
+
     def step(self) -> list[int]:
         """Advance every ACTIVE slot; idle slots report -1.
 

@@ -519,11 +519,10 @@ def _replicated_spec(ndim: int):
 
 
 def _shard_map(body, shard: prg.Shard, in_specs, out_specs):
-    from repro.dist.sharding import shard_map
     # check_vma off: bodies branch on lax.axis_index (device-varying by
     # construction) and merge with an explicit psum
-    return shard_map(body, mesh=shard.mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_vma=False)
+    return jax.shard_map(body, mesh=shard.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _sub_strided(op: str, spec: Strided, impl: str, stride: int, cnt: int,

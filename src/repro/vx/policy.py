@@ -43,12 +43,9 @@ ENV_VAR = "REPRO_VX_IMPL"
 
 
 def _platform() -> str:
-    try:
-        import jax
+    import jax
 
-        return jax.devices()[0].platform
-    except Exception:  # pragma: no cover
-        return "cpu"
+    return jax.devices()[0].platform
 
 
 @dataclasses.dataclass(frozen=True)

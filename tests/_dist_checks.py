@@ -81,8 +81,8 @@ def check_pipeline_equivalence():
     from repro.models.transformer import loss_fn
 
     cfg = get_arch("qwen3-0.6b").smoke  # 2 layers -> 2 stages x 1
-    from repro.dist.sharding import make_mesh
-    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.mesh import make_test_mesh
+    mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
     ctx = make_ctx(mesh)
     from repro.models.transformer import init_params
     params = init_params(cfg, jax.random.key(0))
@@ -270,12 +270,12 @@ def check_sharded_vx_property():
     unsharded oracle bit-exactly across layouts (1- and 2-axis meshes),
     strides of either sign, offsets, and field counts."""
     from repro import vx
-    from repro.dist.sharding import make_mesh
+    from repro.launch.mesh import make_test_mesh
 
     rng = np.random.default_rng(0)
     layouts = [((8,), ("s",)), ((2, 4), ("a", "b")), ((4, 2), ("a", "b"))]
     for shape, axes in layouts:
-        mesh = make_mesh(shape, axes)
+        mesh = make_test_mesh(shape, axes)
         lane = vx.Shard(axes=axes, axis=-1, mesh=mesh)
         outer = vx.Shard(axes=axes, axis=-2, mesh=mesh)
         n = 64
@@ -321,7 +321,8 @@ def check_paged_pool_shard():
     serving path — paged_decode_step with the pool sharded via
     ShardCtx.vx_pool_shard(-4) — is bit-exact vs the replicated step."""
     from repro import vx
-    from repro.dist.sharding import ShardCtx, make_mesh
+    from repro.dist.sharding import ShardCtx
+    from repro.launch.mesh import make_test_mesh
     from repro.models import decode as dec
     from repro.models.transformer import ModelConfig, init_params
 
@@ -337,7 +338,7 @@ def check_paged_pool_shard():
 
     for shape, axes in [((8,), ("s",)), ((2, 4), ("a", "b")),
                         ((4, 2), ("a", "b"))]:
-        mesh = make_mesh(shape, axes)
+        mesh = make_test_mesh(shape, axes)
         shard = vx.Shard(axes=axes, axis=-4, mesh=mesh)
         got = jax.jit(lambda pl, tb: vx.gather(
             spec, pl, table=tb, policy="ref", shard=shard))(pool, table)
@@ -359,7 +360,7 @@ def check_paged_pool_shard():
 
     # the serving path: paged decode with the pool sharded through
     # ShardCtx.vx_pool_shard — bit-exact vs the replicated step
-    mesh = make_mesh((8,), ("s",))
+    mesh = make_test_mesh((8,), ("s",))
     ctx = ShardCtx(mesh=mesh, data_axes=(), model_axis=None,
                    seq_axes=("s",))
     pool_shard = ctx.vx_pool_shard(-4)
@@ -392,7 +393,7 @@ def check_quantized_pool_shard():
     the sharded lowering must be bit-exact vs the replicated one across
     mesh layouts, for full / partial / unallocated tables."""
     from repro import vx
-    from repro.dist.sharding import make_mesh
+    from repro.launch.mesh import make_test_mesh
 
     rng = np.random.default_rng(0)
     ps, pages, P, K, D2 = 4, 6, 16, 2, 8
@@ -407,7 +408,7 @@ def check_quantized_pool_shard():
     want = vx.gather(spec, pool, table=table, scales=scales, policy="ref")
     for shape, axes in [((8,), ("s",)), ((2, 4), ("a", "b")),
                         ((4, 2), ("a", "b"))]:
-        mesh = make_mesh(shape, axes)
+        mesh = make_test_mesh(shape, axes)
         shard = vx.Shard(axes=axes, axis=-4, mesh=mesh)
         got = jax.jit(lambda pl, sc, tb: vx.gather(
             spec, pl, table=tb, scales=sc, policy="ref",

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.models import layers
 from repro.models.attention import decode_attention, flash_attention
 
 
@@ -108,3 +109,17 @@ def test_decode_window_masking():
     dec_f = decode_attention(q, k[:, 6:10], v[:, 6:10], jnp.asarray(4))
     np.testing.assert_allclose(np.asarray(dec_w), np.asarray(dec_f),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("d", [128, 96, 7])
+def test_head_rms_norm_matches_rms_norm(d):
+    """The fixed-order q/k norm computes the same norm as ``rms_norm``
+    (to float32 rounding), for head widths that are and are not powers of
+    two."""
+    x = jax.random.normal(jax.random.key(d), (3, 5, 4, d), jnp.float32)
+    scale = 1.0 + jax.random.normal(jax.random.key(1), (d,), jnp.float32)
+    np.testing.assert_allclose(layers.head_rms_norm(x, scale),
+                               layers.rms_norm(x, scale), rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(layers._pairwise_sum(x)[..., 0],
+                               jnp.sum(x, axis=-1), rtol=1e-5, atol=1e-5)

@@ -2,12 +2,7 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: deterministic fallback
-    import os, sys
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from _hypcompat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import lsdo, rcvrf
 

@@ -3,12 +3,7 @@ checkpoint/rescale exactness (Hypothesis where it pays)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: deterministic fallback
-    import os, sys
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from _hypcompat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.data.aos import FIELDS, pack_records, unpack_records
 from repro.data.pipeline import DataConfig, SyntheticAoSPipeline

@@ -3,12 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: deterministic fallback
-    import os, sys
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from _hypcompat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.optim.adamw import (AdamWConfig, apply_updates, clip_by_global_norm,
                                init_opt_state, schedule)

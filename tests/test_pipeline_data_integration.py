@@ -8,6 +8,7 @@ def _run(args, timeout=600):
     env = dict(os.environ)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(root, "src")
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"   # tests keep no cache
     return subprocess.run([sys.executable, "-m"] + args, env=env, cwd=root,
                           capture_output=True, text=True, timeout=timeout)
 

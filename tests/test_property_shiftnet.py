@@ -10,12 +10,7 @@ separation-monotone. We generate random legal mappings and assert:
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: deterministic fallback
-    import os, sys
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from _hypcompat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import scg, shiftnet
 

@@ -1,6 +1,6 @@
 """Shared quantization helpers (repro/core/quant.py): round-trip error
 bounds per dtype, zero-scale safety, the compression delegation staying
-bit-exact, and the fp8 feature gate."""
+bit-exact, and the fp8 dtypes."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,13 +9,7 @@ import pytest
 from repro.core import quant
 
 
-def _dtypes():
-    out = ["int8"]
-    if quant.supported("float8_e4m3fn"):
-        out.append("float8_e4m3fn")
-    if quant.supported("float8_e5m2"):
-        out.append("float8_e5m2")
-    return out
+_DTYPES = ("int8", "float8_e4m3fn", "float8_e5m2")
 
 
 # --------------------------- name plumbing ----------------------------------
@@ -27,22 +21,22 @@ def test_canonical_aliases_and_rejection():
     assert quant.canonical(np.dtype(np.int8)) == "int8"
     with pytest.raises(ValueError, match="unsupported quantized dtype"):
         quant.canonical("int4")
-    assert not quant.supported("int4")
-    assert quant.supported("int8")
+    with pytest.raises(ValueError, match="unsupported quantized dtype"):
+        quant.pool_dtype("int4")
+    assert quant.pool_dtype("int8") == jnp.int8
 
 
 def test_qmax_values():
     assert quant.qmax("int8") == 127.0            # symmetric, not -128
     assert quant.qmax("float8_e4m3fn") == 448.0   # max finite of e4m3fn
     assert quant.qmax(jnp.int8) == 127.0          # dtype objects too
-    if quant.supported("fp8"):
-        # the bound must agree with what the dtype actually encodes
-        assert float(jnp.finfo(quant.pool_dtype("fp8")).max) == 448.0
+    # the bound must agree with what the dtype actually encodes
+    assert float(jnp.finfo(quant.pool_dtype("fp8")).max) == 448.0
 
 
 # --------------------------- round-trip bound -------------------------------
 
-@pytest.mark.parametrize("dt", _dtypes())
+@pytest.mark.parametrize("dt", _DTYPES)
 @pytest.mark.parametrize("scale", [1e-6, 1.0, 3e3])
 def test_roundtrip_error_within_per_dtype_bound(dt, scale):
     """|x - roundtrip(x)| <= error_bound(dt, max|x|) for every element —
@@ -56,7 +50,7 @@ def test_roundtrip_error_within_per_dtype_bound(dt, scale):
     assert err <= bound * (1 + 1e-6), (dt, scale, err, bound)
 
 
-@pytest.mark.parametrize("dt", _dtypes())
+@pytest.mark.parametrize("dt", _DTYPES)
 def test_roundtrip_extremes_map_exactly(dt):
     """The max-magnitude elements sit exactly at +-qmax, which every
     quantized dtype encodes exactly — so the extremes round-trip with
@@ -68,7 +62,7 @@ def test_roundtrip_extremes_map_exactly(dt):
     assert float(y[1]) == 0.0
 
 
-@pytest.mark.parametrize("dt", _dtypes())
+@pytest.mark.parametrize("dt", _DTYPES)
 def test_zero_scale_writes_zero_never_nan(dt):
     """scale == 0 means "nothing written": quantize must emit 0 (not
     0/0 = NaN — fp8 HAS NaN encodings and one NaN page poisons every

@@ -1,0 +1,112 @@
+"""The served path's Pallas kernels compile for a TPU v5e at real width.
+
+Nothing runs: each test compiles for a described (not attached) v5e chip
+and checks that the compiled program launches a Mosaic kernel
+(``tpu_custom_call``) — what interpret-mode CPU runs cannot show.  The
+topology is described inside a fixture, never at import: only one process
+may hold the TPU library, and every test worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from repro import vx
+from repro.configs import get_arch
+from repro.kernels import _common, kv_interleaved, segment
+from repro.models import decode as dec
+from repro.models import layers
+
+ROWS, N = 4096, 256          # head_dim 128, K|V interleaved
+SLOTS, MAX_LEN, PAGE_SIZE = 8, 2048, 16   # chip_smoke.py's serve geometry
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")   # no compiler logs
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_tpu(monkeypatch):
+    """Kernels lowered for the chip, not the interpreter, and no
+    persistent cache: a described chip's executables cannot be read
+    back here."""
+    monkeypatch.setattr(_common, "interpret_mode", lambda: False)
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _kernels(fn, *shapes) -> int:
+    return jax.jit(fn).lower(*shapes).compile().as_text().count(
+        "tpu_custom_call")
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_segment_deinterleave_compiles(for_tpu, one_chip, dtype):
+    aos = jax.ShapeDtypeStruct((ROWS, N), dtype, sharding=one_chip)
+    assert _kernels(lambda a: segment.deinterleave(a, 2), aos) >= 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_segment_interleave_compiles(for_tpu, one_chip, dtype):
+    half = jax.ShapeDtypeStruct((ROWS, N // 2), dtype, sharding=one_chip)
+    assert _kernels(lambda k, v: segment.interleave([k, v]),
+                    half, half) >= 1
+
+
+def test_paged_kv_split_compiles_for_qwen3_pool(for_tpu, one_chip):
+    """The decode step's fused FIELD=2 split over every layer of the
+    gathered qwen3-0.6b float32 pool."""
+    cfg = get_arch("qwen3-0.6b").model
+    state = jax.eval_shape(lambda: dec.init_paged_cache(
+        cfg, SLOTS, MAX_LEN, PAGE_SIZE, jnp.float32))
+    with vx.use("pallas"):
+        (gathered,) = jax.eval_shape(
+            lambda p, t: kv_interleaved.gather_paged_kv([p], t, PAGE_SIZE),
+            state["blocks"]["pos0"], state["table"])
+        n = _kernels(lambda g: kv_interleaved.split_kv_step([g]),
+                     jax.ShapeDtypeStruct(gathered.shape, gathered.dtype,
+                                          sharding=one_chip))
+    assert n >= 1
+
+
+@pytest.mark.parametrize("impl", ["pallas", "ref"])
+def test_qk_norm_sums_in_fixed_order(for_tpu, one_chip, impl):
+    """One prefill chunk's K|V beats at qwen3-0.6b width (split, k-norm,
+    RoPE, re-interleave): under either lowering the norm's sum of squares
+    compiles to elementwise folds, not a reduce whose order follows the
+    layout the lowering gives K."""
+    seg = vx.Segment(n=N, fields=2)
+
+    def beats(kv, scale):
+        k, v = vx.transpose(seg, kv, policy=impl)
+        k = layers.rope(layers.head_rms_norm(k, scale),
+                        jnp.arange(PAGE_SIZE)[None], 1e6)
+        return vx.transpose(seg, [k, v], policy=impl)
+
+    kv = jax.ShapeDtypeStruct((1, PAGE_SIZE, 8, N), jnp.bfloat16,
+                              sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((N // 2,), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(beats).lower(kv, scale).compile().as_text()
+    assert " reduce(" not in text
+    assert ("tpu_custom_call" in text) == (impl == "pallas")

@@ -264,10 +264,10 @@ def test_same_spec_two_layouts_distinct_cached_programs():
     """PR 4 regression: vx.PLANS keys include the shard layout — the same
     spec lowered against two placements yields two distinct cached
     programs (and a third for the replicated lowering)."""
-    from repro.dist.sharding import make_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.vx import lower as vxlower
-    mesh_a = make_mesh((1,), ("a",))
-    mesh_b = make_mesh((1,), ("b",))
+    mesh_a = make_test_mesh((1,), ("a",))
+    mesh_b = make_test_mesh((1,), ("b",))
     spec = vx.Strided(n=48, stride=3, vl=8, offset=1, dtype="float32")
     progs = [
         vxlower.lower("gather.plan", spec, "ref"),
@@ -298,10 +298,10 @@ def test_layout_key_includes_mesh():
     unequal meshes — even with the same axis names and shard count —
     must not share an entry (e.g. a (2,4) and a (4,2) mesh over the same
     axes)."""
-    from repro.dist.sharding import make_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.vx import lower as vxlower
-    mesh_ab = make_mesh((1, 1), ("a", "b"))
-    mesh_ba = make_mesh((1, 1), ("b", "a"))   # unequal mesh, same names
+    mesh_ab = make_test_mesh((1, 1), ("a", "b"))
+    mesh_ba = make_test_mesh((1, 1), ("b", "a"))   # unequal mesh, same names
     spec = vx.Strided(n=48, stride=2, vl=8, dtype="float32")
     p1 = vxlower.lower("gather.plan", spec, "ref",
                        vx.Shard(axes=("a", "b"), axis=-1, mesh=mesh_ab))
@@ -317,8 +317,8 @@ def test_sharded_gather_many_rejects_heterogeneous_specs():
     """program.fuse reaches the sharded builder with width > 1; a
     heterogeneous group must error, never apply spec 0's plan to every
     stacked row."""
-    from repro.dist.sharding import make_mesh
-    mesh = make_mesh((1,), ("a",))
+    from repro.launch.mesh import make_test_mesh
+    mesh = make_test_mesh((1,), ("a",))
     shard = vx.Shard(axes=("a",), axis=-1, mesh=mesh)
     wins = jnp.stack([jnp.arange(64.0)] * 2)[:, None, :]
     specs = [vx.Strided(n=64, stride=2, offset=0, vl=8),
@@ -333,9 +333,9 @@ def test_sharded_gather_many_rejects_heterogeneous_specs():
 
 
 def test_sharded_lowering_rejects_bad_placements():
-    from repro.dist.sharding import make_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.vx import lower as vxlower
-    mesh = make_mesh((1,), ("a",))
+    mesh = make_test_mesh((1,), ("a",))
     sh_lane = vx.Shard(axes=("a",), axis=-1, mesh=mesh)
     sh_outer = vx.Shard(axes=("a",), axis=-2, mesh=mesh)
     with pytest.raises(ValueError, match="lane axis"):

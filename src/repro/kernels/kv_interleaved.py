@@ -41,10 +41,17 @@ def split_kv_step(kvs: list[jax.Array], *, policy=None, shard=None
     ``shard`` (a ``vx.Shard`` on the cache's sequence axis) lowers the
     merged split shard-locally under ``shard_map`` — the seq-parallel
     long-context cache transposes in place, never gathered or sliced
-    globally (the PR 4 sharding-aware lowering)."""
+    globally (the sharding-aware lowering).
+
+    The launch runs under the name scope ``kv_split``, which names its
+    HLO instruction, and so its ``XLA Ops`` event in a device trace,
+    ``kv_split.<n>`` (the segment kernel also serves FIELD=2 loads that
+    are not KV, such as the SwiGLU gate/up split, so the name comes from
+    here and not from the kernel)."""
     from repro.core import accessfuse
-    return accessfuse.fuse_split_kv(kvs, policy=vx.resolve(policy),
-                                    shard=shard)
+    with jax.named_scope("kv_split"):
+        return accessfuse.fuse_split_kv(kvs, policy=vx.resolve(policy),
+                                        shard=shard)
 
 
 def gather_paged_kv(pools: list[jax.Array], table: jax.Array,

@@ -405,6 +405,8 @@ def run(args) -> int:
           f"({generated / max(dt, 1e-9):.1f} tok/s on {device_label()}); "
           f"preemptions={stats['preemptions']} "
           f"prefill_chunks={stats['prefill_chunks']} "
+          f"decode_steps={stats['decode_steps']} "
+          f"host_syncs={stats['host_syncs']} "
           f"watchdog_breaches={stats.get('watchdog_breaches', 0)}")
     if "prefix" in stats:
         px = stats["prefix"]

@@ -79,12 +79,27 @@ class PagedCache:
                                           cache_dtype,
                                           num_pages=self.num_pages,
                                           quantize=kv_quant)
+        # Each program is a named function, so that its XLA module reads
+        # ``jit_<name>`` in a device trace.
+        def release_slot(c, s):
+            return dec.paged_release_slot(cfg, c, s)
+
+        def adopt_prefix(c, s, ids):
+            return dec.paged_adopt_prefix(cfg, c, s, ids)
+
+        def fork_page(c, s, i, src, p):
+            return dec.paged_fork_page(cfg, c, s, i, src, pos_to=p)
+
+        def addref(c, ids):
+            return dec.paged_addref(cfg, c, ids)
+
+        def deref_pages(c, ids):
+            return dec.paged_deref_pages(cfg, c, ids)
+
         # state donated on every mutation: release/insert return a full
         # new pytree, and the pool is the big buffer — without donation
         # each finish()/admission would pay a pool copy
-        self._release = jax.jit(
-            lambda c, s: dec.paged_release_slot(cfg, c, s),
-            donate_argnums=0)
+        self._release = jax.jit(release_slot, donate_argnums=0)
         # one jit entry per PADDED prompt length (a page multiple): the
         # true length rides in as a traced operand, so mixed-length
         # traffic costs at most pages_per_seq distinct traces
@@ -92,19 +107,10 @@ class PagedCache:
         # prefix-sharing entry points (PR 8): page-run adoption, CoW tail
         # fork, and the trie's external refcount pin — all donate the
         # state like release/insert do
-        self._adopt = jax.jit(
-            lambda c, s, ids: dec.paged_adopt_prefix(cfg, c, s, ids),
-            donate_argnums=0)
-        self._fork = jax.jit(
-            lambda c, s, i, src, p: dec.paged_fork_page(
-                cfg, c, s, i, src, pos_to=p),
-            donate_argnums=0)
-        self._addref = jax.jit(
-            lambda c, ids: dec.paged_addref(cfg, c, ids),
-            donate_argnums=0)
-        self._deref = jax.jit(
-            lambda c, ids: dec.paged_deref_pages(cfg, c, ids),
-            donate_argnums=0)
+        self._adopt = jax.jit(adopt_prefix, donate_argnums=0)
+        self._fork = jax.jit(fork_page, donate_argnums=0)
+        self._addref = jax.jit(addref, donate_argnums=0)
+        self._deref = jax.jit(deref_pages, donate_argnums=0)
         # external refcount provider (set by the scheduler to the prefix
         # trie's page_refs): pages pinned OUTSIDE any slot's table that
         # the conservation audit must account for

@@ -75,7 +75,6 @@ state pays zero retraces and zero plan-cache misses
 """
 from __future__ import annotations
 
-import functools
 import time
 from typing import Callable, Sequence
 
@@ -90,6 +89,11 @@ from repro.serve.lifecycle import (AdmissionError, AdmissionQueue, Request,
                                    RequestState)
 from repro.serve.paged_cache import PagedCache
 from repro.serve.prefix_cache import PrefixCache
+
+# Host spans of the serve path (``serve.*``).  A TraceAnnotation writes an
+# event only while a profiler is running, onto the device trace's clock;
+# otherwise it costs about a microsecond and records nothing.
+_span = jax.profiler.TraceAnnotation
 
 
 def sample_tokens(logits: jax.Array, keys, *, temperature: float = 0.0,
@@ -217,32 +221,45 @@ class Scheduler:
         self.temperature, self.top_k = float(temperature), top_k
         vx.warm(2 * cfg.hd, strided=False, fields=(2,),
                 policy=cfg.vx_policy)
+        temperature = self.temperature
+
+        # Each program is a named function, so that its XLA module reads
+        # ``jit_<name>`` in a device trace.
+        def decode_step(p, c, t, a):
+            return dec.paged_decode_step(p, c, t, cfg, None, active=a,
+                                         fuse=fuse_step)
+
+        def sample(logits, keys):
+            return sample_tokens(logits, keys, temperature=temperature,
+                                 top_k=top_k)
+
+        def sample_checked(logits, keys):
+            return Scheduler._sample_and_check(
+                logits, keys, temperature=temperature, top_k=top_k)
+
+        def split_keys(ks):
+            return jnp.swapaxes(jax.vmap(
+                lambda k: jax.random.split(k, 2))(ks), 0, 1)
+
+        def prefill_chunk(p, c, t, s, n):
+            return dec.paged_prefill_chunk(p, c, t, cfg, None, slot=s,
+                                           count=n)
+
         # cache donated: the pool is the big buffer and the step replaces
         # it wholesale — without donation every append pays a pool copy
-        self._step = jax.jit(
-            lambda p, c, t, a: dec.paged_decode_step(
-                p, c, t, cfg, None, active=a, fuse=fuse_step),
-            donate_argnums=1)
-        self._sample = jax.jit(functools.partial(
-            sample_tokens, temperature=self.temperature, top_k=top_k))
+        self._step = jax.jit(decode_step, donate_argnums=1)
+        self._sample = jax.jit(sample)
         # guard variant: sampling fused with the per-slot finite check so
         # the guard costs one extra reduction, not a second step
-        self._sample_guarded = jax.jit(functools.partial(
-            self._sample_and_check, temperature=self.temperature,
-            top_k=top_k))
-        self._split_keys = jax.jit(
-            lambda ks: jnp.swapaxes(jax.vmap(
-                lambda k: jax.random.split(k, 2))(ks), 0, 1))
+        self._sample_guarded = jax.jit(sample_checked)
+        self._split_keys = jax.jit(split_keys)
         self._keys = jax.random.split(jax.random.key(seed), slots)
         # chunked prefill: ONE fixed-width jit (token width = page size;
         # slot and true count are traced operands) covers every chunk of
         # every prompt — the same trace and the same vx access plans,
         # so prefill adds nothing to the steady-state plan-cache
         # footprint.  State donated like the decode step.
-        self._chunk = jax.jit(
-            lambda p, c, t, s, n: dec.paged_prefill_chunk(
-                p, c, t, cfg, None, slot=s, count=n),
-            donate_argnums=1)
+        self._chunk = jax.jit(prefill_chunk, donate_argnums=1)
         self.chunk_pages = int(chunk_pages)
         self._prefilling: dict[int, int] = {}   # slot -> prefilled tokens
         self.prefill_chunks = 0
@@ -264,25 +281,31 @@ class Scheduler:
                 debug_invariants=debug_invariants)
             vx.warm(2 * draft_cfg.hd, strided=False, fields=(2,),
                     policy=draft_cfg.vx_policy)
-            self._verify = jax.jit(
-                lambda p, c, t, n, a: dec.paged_verify_step(
-                    p, c, t, cfg, None, n_draft=n, active=a,
-                    fuse=fuse_step),
-                donate_argnums=1)
-            self._verify_finite = jax.jit(
-                lambda lg: jnp.all(jnp.isfinite(lg.astype(jnp.float32)),
-                                   axis=-1))
-            self._dstep = jax.jit(
-                lambda p, c, t, a: dec.paged_decode_step(
-                    p, c, t, draft_cfg, None, active=a, fuse=fuse_step),
-                donate_argnums=1)
-            self._dchunk = jax.jit(
-                lambda p, c, t, s, n: dec.paged_prefill_chunk(
-                    p, c, t, draft_cfg, None, slot=s, count=n),
-                donate_argnums=1)
-            self._dtrunc = jax.jit(
-                lambda c, np_: dec.paged_truncate(draft_cfg, c, np_),
-                donate_argnums=0)
+
+            def verify_step(p, c, t, n, a):
+                return dec.paged_verify_step(p, c, t, cfg, None, n_draft=n,
+                                             active=a, fuse=fuse_step)
+
+            def verify_finite(lg):
+                return jnp.all(jnp.isfinite(lg.astype(jnp.float32)),
+                               axis=-1)
+
+            def draft_step(p, c, t, a):
+                return dec.paged_decode_step(p, c, t, draft_cfg, None,
+                                             active=a, fuse=fuse_step)
+
+            def draft_chunk(p, c, t, s, n):
+                return dec.paged_prefill_chunk(p, c, t, draft_cfg, None,
+                                               slot=s, count=n)
+
+            def draft_truncate(c, np_):
+                return dec.paged_truncate(draft_cfg, c, np_)
+
+            self._verify = jax.jit(verify_step, donate_argnums=1)
+            self._verify_finite = jax.jit(verify_finite)
+            self._dstep = jax.jit(draft_step, donate_argnums=1)
+            self._dchunk = jax.jit(draft_chunk, donate_argnums=1)
+            self._dtrunc = jax.jit(draft_truncate, donate_argnums=0)
         self._spec_k = [1] * slots   # per-slot verify width (request K)
         self._dpos = [0] * slots     # draft tokens consumed (host mirror)
         self.spec_steps = 0          # verify steps taken
@@ -321,6 +344,10 @@ class Scheduler:
         self._step_ewma = 0.0
         self.nan_failures = 0
         self.preemptions = 0
+        # serve-path counters (stats(), printed by the serve CLI): decode
+        # or verify steps taken, and device->host reads made (see _sync)
+        self.decode_steps = 0
+        self.host_syncs = 0
         # per-request latency accounting (host clock, zero device work):
         # TTFT = first decoded token minus submit; inter-token latency is
         # the per-token gap between appends (a K-token speculative commit
@@ -337,6 +364,13 @@ class Scheduler:
         return (sample_tokens(logits, keys, temperature=temperature,
                               top_k=top_k),
                 jnp.all(jnp.isfinite(lg32), axis=-1))
+
+    def _sync(self, read, *args):
+        """``read(*args)``, one device->host read of the serve path:
+        counted in ``host_syncs`` and spanned as ``serve.host_sync``."""
+        with _span("serve.host_sync"):
+            self.host_syncs += 1
+            return read(*args)
 
     # -- admission ----------------------------------------------------------
     def free_slot(self) -> int | None:
@@ -465,9 +499,10 @@ class Scheduler:
             if m.run:
                 self.cache.adopt_prefix(slot, list(m.run))
                 done = len(m.run) * self.cache.page_size
-            if m.fork_src >= 0 and self.cache.free_pages() >= 1:
-                self.cache.fork_page(slot, len(m.run), m.fork_src,
-                                     done + m.fork_len)
+            if m.fork_src >= 0 and self._sync(self.cache.free_pages) >= 1:
+                # fork_page reads the free count once more, then forks
+                self._sync(self.cache.fork_page, slot, len(m.run),
+                           m.fork_src, done + m.fork_len)
                 done += m.fork_len
         self._prefilling[slot] = done
         self._pos[slot] = done
@@ -484,25 +519,27 @@ class Scheduler:
         pre = req.prompt[:-1]
         ps = self.cache.page_size
         c = self._prefilling[slot]
+        free = self.cache.free_pages
         for _ in range(chunks):
             if c >= len(pre):
                 break
-            n = min(ps, len(pre) - c)
-            newp = self.cache.pages_needed(c + n) - \
-                (0 if c == 0 else -(-c // ps))
-            if self.cache.free_pages() < newp:
-                self._evict_prefix(newp - self.cache.free_pages())
-            if self.cache.free_pages() < newp:
-                return False
-            tok = jnp.asarray(pre[c:c + n] + [0] * (ps - n), jnp.int32)
-            self.cache.state = self._chunk(self.params, self.cache.state,
-                                           tok, jnp.int32(slot),
-                                           jnp.int32(n))
-            self.cache._maybe_check()
-            c += n
-            self._prefilling[slot] = c
-            self._pos[slot] = c
-            self.prefill_chunks += 1
+            with _span("serve.prefill_chunk", slot=slot, rid=req.rid):
+                n = min(ps, len(pre) - c)
+                newp = self.cache.pages_needed(c + n) - \
+                    (0 if c == 0 else -(-c // ps))
+                if self._sync(free) < newp:
+                    self._evict_prefix(newp - self._sync(free))
+                if self._sync(free) < newp:
+                    return False
+                tok = jnp.asarray(pre[c:c + n] + [0] * (ps - n), jnp.int32)
+                self.cache.state = self._chunk(self.params, self.cache.state,
+                                               tok, jnp.int32(slot),
+                                               jnp.int32(n))
+                self.cache._maybe_check()
+                c += n
+                self._prefilling[slot] = c
+                self._pos[slot] = c
+                self.prefill_chunks += 1
         if c >= len(pre):
             self._finish_prefill(slot)
         return True
@@ -516,8 +553,8 @@ class Scheduler:
         self._prefilling.pop(slot, None)
         pre = req.prompt[:-1]
         if self.prefix is not None and pre:
-            new = self.prefix.publish(slot, pre,
-                                      self.cache.table_row(slot))
+            new = self.prefix.publish(
+                slot, pre, self._sync(self.cache.table_row, slot))
             if new:
                 self.cache.addref(new)
         self._fed[slot] = len(req.prompt) - 1
@@ -693,13 +730,15 @@ class Scheduler:
         t0 = time.perf_counter()
         decoding = [self.active[s] and s not in self._prefilling
                     for s in range(self.slots)]
-        if self.speculate > 1 and any(
-                decoding[s] and self._spec_k[s] > 1
-                for s in range(self.slots)):
-            out = self._step_speculative(decoding)
-        else:
-            out = self._step_plain(decoding)
-        self.cache._maybe_check()
+        speculative = self.speculate > 1 and any(
+            decoding[s] and self._spec_k[s] > 1 for s in range(self.slots))
+        self.decode_steps += 1
+        with _span("serve.verify" if speculative else "serve.decode"):
+            if speculative:
+                out = self._step_speculative(decoding)
+            else:
+                out = self._step_plain(decoding)
+            self.cache._maybe_check()
         dt = time.perf_counter() - t0
         self._step_ewma = dt if self._step_ewma == 0.0 else \
             0.8 * self._step_ewma + 0.2 * dt
@@ -708,30 +747,38 @@ class Scheduler:
         return out
 
     def _step_plain(self, decoding: list[bool]) -> list[int]:
-        """The single-token decode step (pre-PR 10 semantics, verbatim)."""
-        cur = jnp.asarray([self.tokens[s][self._fed[s]]
-                           if decoding[s] else 0
-                           for s in range(self.slots)], jnp.int32)
-        act = jnp.asarray(decoding)
-        logits, self.cache.state = self._step(self.params,
-                                              self.cache.state, cur, act)
-        if self._taint is not None:      # chaos-only NaN injection hook
-            mask = jnp.asarray(self._taint)[:, None]
-            logits = jnp.where(mask, jnp.float32(jnp.nan),
-                               logits.astype(jnp.float32)).astype(
-                                   logits.dtype)
-            self._taint = None
-        self.last_logits = logits
-        if self.temperature > 0.0:
-            self._keys, sub = self._split_keys(self._keys)
-        else:
-            sub = self._keys
-        if self.guard_nan:
-            nxt, fin = self._sample_guarded(logits, sub)
-            nxt, fin = np.asarray(nxt), np.asarray(fin)
-        else:
-            nxt = np.asarray(self._sample(logits, sub))
-            fin = None                 # ONE host sync for all slots
+        """The single-token decode step: the feed built and the step and
+        sampling dispatched (``serve.decode.dispatch``), then the sampled
+        tokens read back (``serve.decode.readback``), one host sync for
+        all slots (two with the NaN guard)."""
+        with _span("serve.decode.dispatch"):
+            cur = jnp.asarray([self.tokens[s][self._fed[s]]
+                               if decoding[s] else 0
+                               for s in range(self.slots)], jnp.int32)
+            act = jnp.asarray(decoding)
+            logits, self.cache.state = self._step(self.params,
+                                                  self.cache.state, cur, act)
+            if self._taint is not None:      # chaos-only NaN injection hook
+                mask = jnp.asarray(self._taint)[:, None]
+                logits = jnp.where(mask, jnp.float32(jnp.nan),
+                                   logits.astype(jnp.float32)).astype(
+                                       logits.dtype)
+                self._taint = None
+            self.last_logits = logits
+            if self.temperature > 0.0:
+                self._keys, sub = self._split_keys(self._keys)
+            else:
+                sub = self._keys
+            if self.guard_nan:
+                nxt, fin = self._sample_guarded(logits, sub)
+            else:
+                nxt, fin = self._sample(logits, sub), None
+        with _span("serve.decode.readback"):
+            nxt = np.asarray(nxt)
+            self.host_syncs += 1
+            if fin is not None:
+                fin = np.asarray(fin)
+                self.host_syncs += 1
         out = []
         t_now = self.clock()
         seq_cap = self.cache.pages_per_seq * self.cache.page_size
@@ -795,7 +842,8 @@ class Scheduler:
             if r == avail and k > r:
                 need[s] = k - r          # top up with draft-model tokens
         if any(need):
-            drafts = self._draft_pump(need)
+            with _span("serve.draft"):
+                drafts = self._draft_pump(need)
             for s in range(self.slots):
                 if need[s]:
                     got = drafts[s]
@@ -813,10 +861,11 @@ class Scheduler:
             self._taint = None
         self.last_logits = logits[:, 0, :]
         if self.guard_nan:
-            fin = np.asarray(self._verify_finite(logits))   # (B, K)
+            fin = self._sync(np.asarray,
+                             self._verify_finite(logits))   # (B, K)
         else:
             fin = None
-        o_np, cm = np.asarray(o), np.asarray(commit)
+        o_np, cm = self._sync(np.asarray, o), self._sync(np.asarray, commit)
         out = []
         t_now = self.clock()
         seq_cap = self.cache.pages_per_seq * self.cache.page_size
@@ -896,7 +945,7 @@ class Scheduler:
                 act[s] = True
             lg, dc.state = self._dstep(self.draft_params, dc.state,
                                        jnp.asarray(feed), jnp.asarray(act))
-            nxt = np.asarray(jnp.argmax(lg, axis=-1))
+            nxt = self._sync(np.asarray, jnp.argmax(lg, axis=-1))
             for s in list(pend):
                 keep = self._dpos[s] >= len(self.tokens[s]) - 1
                 self._dpos[s] += 1
@@ -931,12 +980,36 @@ class Scheduler:
         pressure when ``preemption`` is on), advance each mid-prefill
         slot by ``chunk_pages`` chunks, step the active set, retire
         finished / expired requests.  Returns requests that went
-        TERMINAL this tick."""
-        now = self.clock()
-        done: list[Request] = list(self.queue.expire(now))
-        # admission pump: highest priority first; under pressure, evict
-        # strictly-lower-priority victims (equal priority never preempts
-        # equal priority — no livelock)
+        TERMINAL this tick.
+
+        Spans (``serve.*``, on the device trace's clock while a profiler
+        runs): ``serve.tick`` over all of it; ``serve.admit``,
+        ``serve.prefill_chunk`` (one per chunk, with ``slot`` and
+        ``rid``), ``serve.page_guard``, ``serve.decode`` (or
+        ``serve.verify``) and ``serve.retire`` inside it; and
+        ``serve.host_sync`` around each device->host read."""
+        with _span("serve.tick"):
+            with _span("serve.admit"):
+                done: list[Request] = list(self.queue.expire(self.clock()))
+                self._admit_pump()
+            self._prefill_pump()
+            if self.preemption and any(self.active):
+                with _span("serve.page_guard"):
+                    self._page_guard()
+            if any(self.active[s] and s not in self._prefilling
+                   for s in range(self.slots)):
+                self.step()
+            with _span("serve.retire"):
+                done += self._retire()
+            # requests failed mid-step (NaN guard, chaos slot death)
+            done.extend(self._newly_terminal)
+            self._newly_terminal.clear()
+            return done
+
+    def _admit_pump(self) -> None:
+        """Admission pump: highest priority first; under pressure, evict
+        strictly-lower-priority victims (equal priority never preempts
+        equal priority — no livelock)."""
         while True:
             req = self.queue.pop()
             if req is None:
@@ -956,12 +1029,14 @@ class Scheduler:
                             pass       # still starved: requeue, stop
                 self.queue.push(req, force=True)   # retry next tick
                 break
-        # chunked-prefill pump: each mid-prefill slot advances by the
-        # per-tick chunk budget, interleaved with the decode step below
-        # — a long prompt streams in while the active set keeps
-        # generating.  A slot the pool cannot back even after trie
-        # eviction is preempted (PREFILLING -> PREEMPTED) and resumes
-        # when pages free up, rather than silently starving.
+
+    def _prefill_pump(self) -> None:
+        """Chunked-prefill pump: each mid-prefill slot advances by the
+        per-tick chunk budget, interleaved with the decode step — a long
+        prompt streams in while the active set keeps generating.  A slot
+        the pool cannot back even after trie eviction is preempted
+        (PREFILLING -> PREEMPTED) and resumes when pages free up, rather
+        than silently starving."""
         for s in list(self._prefilling):
             if not self.active[s]:
                 continue
@@ -970,43 +1045,45 @@ class Scheduler:
                     self.preempt(s)
                 else:
                     self.fail_slot(s, "page pool exhausted mid-prefill")
-        # in-step page-pressure guard: if this step's page-boundary
-        # crossers outnumber the free stack, the device allocator would
-        # degrade locally (starved appends drop).  Evict trie orphans
-        # first (they free pages without killing work), then preempt
-        # victims to keep every surviving slot's stream intact.
-        if self.preemption and any(self.active):
-            ps = self.cache.page_size
-            n_seq = self.cache.pages_per_seq
 
-            def _step_new_pages(s: int) -> int:
-                # pages this step may allocate for slot s: a plain slot
-                # crosses at most one boundary, a speculative slot may
-                # append up to _spec_k tokens before rollback
-                p = self._pos[s]
-                first = -(-p // ps)
-                last = min((p + self._spec_k[s] - 1) // ps, n_seq - 1)
-                return max(0, last - first + 1)
+    def _page_guard(self) -> None:
+        """In-step page-pressure guard: if this step's page-boundary
+        crossers outnumber the free stack, the device allocator would
+        degrade locally (starved appends drop).  Evict trie orphans
+        first (they free pages without killing work), then preempt
+        victims to keep every surviving slot's stream intact."""
+        ps = self.cache.page_size
+        n_seq = self.cache.pages_per_seq
 
-            crossers = {s: _step_new_pages(s) for s in range(self.slots)
-                        if self.active[s] and s not in self._prefilling
-                        and _step_new_pages(s) > 0}
-            short = sum(crossers.values()) - self.cache.free_pages()
-            if short > 0:
-                self._evict_prefix(short)
-            for _ in range(self.slots):
-                live = {s: n for s, n in crossers.items()
-                        if self.active[s]}
-                if sum(live.values()) <= self.cache.free_pages():
-                    break
-                victim = self._victim()
-                if victim is None or (victim in live and len(live) == 1):
-                    break              # nothing to gain: degrade locally
-                self.preempt(victim)
-        if any(self.active[s] and s not in self._prefilling
-               for s in range(self.slots)):
-            self.step()
-        # retire: generation budget reached, or running past deadline
+        def _step_new_pages(s: int) -> int:
+            # pages this step may allocate for slot s: a plain slot
+            # crosses at most one boundary, a speculative slot may
+            # append up to _spec_k tokens before rollback
+            p = self._pos[s]
+            first = -(-p // ps)
+            last = min((p + self._spec_k[s] - 1) // ps, n_seq - 1)
+            return max(0, last - first + 1)
+
+        crossers = {s: _step_new_pages(s) for s in range(self.slots)
+                    if self.active[s] and s not in self._prefilling
+                    and _step_new_pages(s) > 0}
+        short = sum(crossers.values()) - self._sync(self.cache.free_pages)
+        if short > 0:
+            self._evict_prefix(short)
+        for _ in range(self.slots):
+            live = {s: n for s, n in crossers.items()
+                    if self.active[s]}
+            if sum(live.values()) <= self._sync(self.cache.free_pages):
+                break
+            victim = self._victim()
+            if victim is None or (victim in live and len(live) == 1):
+                break              # nothing to gain: degrade locally
+            self.preempt(victim)
+
+    def _retire(self) -> list[Request]:
+        """Retire slots whose generation budget is reached, or that run
+        past their deadline; returns the requests retired."""
+        done = []
         for s in range(self.slots):
             req = self._slot_req[s]
             if req is None or not self.active[s]:
@@ -1025,9 +1102,6 @@ class Scheduler:
                        error="deadline expired while running")
                 self._release_slot(s)
                 done.append(req)
-        # requests failed mid-step (NaN guard, chaos slot death)
-        done.extend(self._newly_terminal)
-        self._newly_terminal.clear()
         return done
 
     def drained(self) -> bool:
@@ -1107,7 +1181,9 @@ class Scheduler:
                    invariant_checks=self.cache.invariant_checks,
                    step_ewma_s=self._step_ewma,
                    prefilling=len(self._prefilling),
-                   prefill_chunks=self.prefill_chunks)
+                   prefill_chunks=self.prefill_chunks,
+                   decode_steps=self.decode_steps,
+                   host_syncs=self.host_syncs)
         if self.prefix is not None:
             out["prefix"] = self.prefix.stats()
             out["shared_pages"] = int(

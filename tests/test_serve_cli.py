@@ -1,6 +1,7 @@
 """The serve CLI's exit contract in clean serving, and where the compile
 cache goes.  Runs in-process at the smoke geometry; ``run`` (unlike
 ``main``) leaves the persistent compile cache off."""
+import re
 from pathlib import Path
 
 import jax
@@ -18,6 +19,17 @@ def test_clean_serve_exits_zero_when_every_request_finishes(capsys):
     out = capsys.readouterr().out
     assert f"tok/s on {serve.device_label()}" in out
     assert out.count("finished") == 2
+
+
+def test_summary_line_prints_the_serve_path_counters(capsys):
+    """The summary line carries the scheduler's decode steps and its
+    device->host reads: every decode step reads its sampled tokens back,
+    and every prefill chunk reads the free page count first."""
+    assert serve.run(serve.parse_args(SMOKE)) == 0
+    m = re.search(r"prefill_chunks=(\d+) decode_steps=(\d+) "
+                  r"host_syncs=(\d+) ", capsys.readouterr().out)
+    chunks, steps, syncs = map(int, m.groups())
+    assert steps == 3 and syncs >= steps + chunks
 
 
 def test_clean_serve_exits_nonzero_when_tick_cap_hit(capsys, monkeypatch):

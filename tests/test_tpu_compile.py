@@ -19,6 +19,8 @@ from repro.configs import get_arch
 from repro.kernels import _common, kv_interleaved, segment
 from repro.models import decode as dec
 from repro.models import layers
+from repro.models.transformer import init_params
+from repro.serve.scheduler import Scheduler
 
 ROWS, N = 4096, 256          # head_dim 128, K|V interleaved
 SLOTS, MAX_LEN, PAGE_SIZE = 8, 2048, 16   # chip_smoke.py's serve geometry
@@ -88,6 +90,48 @@ def test_paged_kv_split_compiles_for_qwen3_pool(for_tpu, one_chip):
                      jax.ShapeDtypeStruct(gathered.shape, gathered.dtype,
                                           sharding=one_chip))
     assert n >= 1
+
+
+def test_decode_step_and_its_split_carry_stable_names(for_tpu, one_chip):
+    """The scheduler's decode step compiles as the module
+    ``jit_decode_step``, and its whole-step FIELD=2 split is its one
+    instruction named ``kv_split``, a Mosaic call: the names a device
+    trace's ``XLA Modules`` and ``XLA Ops`` events carry (smoke widths:
+    the names do not depend on them).  The prefill chunk has no
+    ``kv_split`` instruction, so the device time of every ``kv_split``
+    op is the decode steps' split alone."""
+    cfg = get_arch("qwen3-0.6b").smoke
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def split_names(text):
+        return [line.split(" = ")[0].strip() for line in text.splitlines()
+                if line.strip().startswith(("%kv_split", "ROOT %kv_split"))]
+    params = on_chip(jax.eval_shape(lambda: init_params(
+        cfg, jax.random.key(0))))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    with vx.use("pallas"):
+        sched = Scheduler(cfg, params, slots=SLOTS, max_len=128,
+                          page_size=PAGE_SIZE)
+        state = on_chip(sched.cache.state)
+        step = sched._step.lower(
+            params, state,
+            jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((SLOTS,), bool, sharding=one_chip),
+        ).compile().as_text()
+        chunk = sched._chunk.lower(
+            params, state,
+            jax.ShapeDtypeStruct((PAGE_SIZE,), jnp.int32, sharding=one_chip),
+            i32, i32).compile().as_text()
+    assert step.startswith("HloModule jit_decode_step,")
+    (name,) = split_names(step)
+    (call,) = [line for line in step.splitlines()
+               if line.strip().startswith(name + " = ")]
+    assert 'custom_call_target="tpu_custom_call"' in call
+    assert chunk.startswith("HloModule jit_prefill_chunk,")
+    assert split_names(chunk) == []
 
 
 @pytest.mark.parametrize("impl", ["pallas", "ref"])

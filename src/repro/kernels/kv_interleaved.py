@@ -5,9 +5,11 @@ of a token are ONE contiguous beat, so a decode-step append is a single
 coalesced write (the paper's one-transaction-per-segment), and attention-time
 splitting is a FIELD=2 segment load.  All routing goes through the
 declarative vx API: a ``Segment(fields=2)`` spec, a policy (the model's
-``cfg.vx_policy``) picking the lowering — under ``pallas`` the split/pack
-run the FUSED segment kernel (one compiled-permutation pass producing both
-K and V, core/shiftplan.py), never two sequential gather networks.
+``cfg.vx_policy``) picking the lowering — under ``pallas`` the split runs
+the segment kernel's transpose route (both K and V from one pass through
+the transpose unit) and the pack the FUSED segment kernel (one
+compiled-permutation pass, core/shiftplan.py), never two sequential gather
+networks.
 """
 from __future__ import annotations
 

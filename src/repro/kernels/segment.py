@@ -1,8 +1,13 @@
 """Segment (AoS <-> SoA) Pallas kernels — compiled bulk transposition.
 
 A segment access with FIELDS=f over an n-lane beat is ONE lane permutation
-(AoS -> concatenated SoA fields, or back).  The static-plan compiler
-(core/shiftplan.py) routes it in a SINGLE kernel either as
+(AoS -> concatenated SoA fields, or back).  A segment LOAD of 32-bit words
+whose rows come in 128-row chunks takes the TRANSPOSE route (the column-wise
+access of EARTH's shifted register bank, done by the TPU's transpose unit):
+each chunk is transposed into a VMEM scratch, lanes becoming sublanes, and
+field f is the sublane-strided read ``f, f+fields, ...`` transposed back —
+no lane shifts at all.  Every other access routes through the static-plan
+compiler (core/shiftplan.py) in a SINGLE kernel either as
 
   * a FUSED permutation pass — one O(log n) Benes/butterfly sweep of static
     shifts + constant-mask selects handling ALL fields at once (the RCVRF
@@ -11,10 +16,10 @@ A segment access with FIELDS=f over an n-lane beat is ONE lane permutation
     cheaper (small field counts) — still pruned single-shift layers with
     constant masks, never the dynamic triple-shift loop.
 
-No scratch "segment buffer" is allocated: each field's lanes are sliced
-straight out of the routed beat into its output block (immediate writeback,
-Fig. 4c).  ``fused=False`` keeps the per-field dynamic-count networks as
-the fallback/oracle.
+The shift routes allocate no scratch "segment buffer": each field's lanes
+are sliced straight out of the routed beat into its output block
+(immediate writeback, Fig. 4c).  ``fused=False`` keeps the per-field
+dynamic-count networks as the fallback/oracle.
 """
 from __future__ import annotations
 
@@ -24,9 +29,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import scg, shiftnet, shiftplan
 from repro.kernels import _common
+from repro.vx.cache import SEGMENT_LOADS
 
 
 # One concatenated (S, n) mask operand for several plans (shared helper).
@@ -101,21 +108,81 @@ def _deint_dyn_kernel(aos_ref, *o_refs, fields: int):
         o_refs[f][...] = jax.lax.slice(res.payload, (0, 0), (aos.shape[0], m))
 
 
-def deinterleave(aos: jax.Array, fields: int, *,
-                 fused: bool = True) -> list[jax.Array]:
-    """(..., fields*m) -> fields x (..., m)   (segment load)."""
-    n = aos.shape[-1]
-    assert n % fields == 0
+# Transpose route.  One chunk is one (128, n) -> (n, 128) transpose: a
+# strided read needs a scratch whose last dim is exactly 128 lanes.
+_CHUNK = 128
+# Input block bytes: a step moves this in and the same out.  At n = 256 on
+# a v5e, 1024-row blocks split the decode step's pool at 601 GB/s against
+# 388 GB/s for 128 rows, and 2048 rows gain nothing (PERF.md §6).
+_BLOCK_BYTES = 1024 * 1024
+# VMEM a step may take: input and output blocks double-buffered (4x the
+# input block) plus the transposed chunks (1x), inside v5e's 16 MiB default.
+_VMEM_BYTES = 12 * 1024 * 1024
+
+
+def transpose_block_rows(rows: int, n: int, fields: int, dtype) -> int:
+    """Block height of the transpose route for an (rows, n) segment load,
+    or 0 where the route does not apply and the shift plans route it.
+
+    The route needs 32-bit words, whole 128-row chunks, an n of whole
+    128-lane vregs and fields of whole 8-sublane tiles; a block of one
+    chunk must fit the VMEM budget (a GLU split at n = 6144 does not).
+    The height is the largest power-of-two number of chunks dividing
+    ``rows`` within ``_BLOCK_BYTES`` (at least one chunk)."""
     m = n // fields
-    flat, lead = _common.flatten_rows(aos)
+    word = jnp.dtype(dtype).itemsize
+    if (word != 4 or rows % _CHUNK or n % 128 or m % 8
+            or 5 * _CHUNK * n * word > _VMEM_BYTES):
+        return 0
+    rt = _CHUNK
+    while rows % (2 * rt) == 0 and 2 * rt * n * word <= _BLOCK_BYTES:
+        rt *= 2
+    return rt
+
+
+def _deint_transpose_kernel(aos_ref, *refs, fields: int):
+    o_refs, t_ref = refs[:fields], refs[fields]
+    n = aos_ref.shape[-1]
+    m = n // fields
+    for c in range(t_ref.shape[0]):        # static: _BLOCK_BYTES bounds it
+        rows = pl.ds(c * _CHUNK, _CHUNK)
+        t_ref[c] = aos_ref[rows, :].T                   # lanes -> sublanes
+        for f in range(fields):
+            o_refs[f][rows, :] = t_ref[c, pl.ds(f, m, stride=fields), :].T
+
+
+def _deint_transpose(flat: jax.Array, fields: int, rt: int
+                     ) -> list[jax.Array]:
+    """(R, n) 32-bit AoS -> fields x (R, m) in row blocks of ``rt``."""
+    r, n = flat.shape
+    m = n // fields
+    return _common.call(
+        functools.partial(_deint_transpose_kernel, fields=fields),
+        out_shape=tuple(jax.ShapeDtypeStruct((r, m), flat.dtype)
+                        for _ in range(fields)),
+        grid=(r // rt,),
+        in_specs=[pl.BlockSpec((rt, n), lambda i: (i, 0))],
+        out_specs=tuple(pl.BlockSpec((rt, m), lambda i: (i, 0))
+                        for _ in range(fields)),
+        scratch_shapes=[pltpu.VMEM((rt // _CHUNK, n, _CHUNK), flat.dtype)],
+    )(flat)
+
+
+def _deint_shift(flat: jax.Array, fields: int, fused: bool
+                 ) -> list[jax.Array]:
+    """(R, n) AoS -> fields x (R, m) through the shift networks: the
+    cost-modeled plans (``fused``) or the dynamic-count oracle."""
+    n = flat.shape[-1]
+    m = n // fields
     flat, r0, rt = _common.tile_rows(flat)
     grid = (_common.row_grid(flat.shape[0], rt),)
-    out_shape = tuple(jax.ShapeDtypeStruct((flat.shape[0], m), aos.dtype)
+    out_shape = tuple(jax.ShapeDtypeStruct((flat.shape[0], m), flat.dtype)
                       for _ in range(fields))
     out_specs = tuple(pl.BlockSpec((rt, m), lambda i: (i, 0))
                       for _ in range(fields))
     if fused:
         mode, plans = shiftplan.segment_deinterleave_plans(n, fields)
+        SEGMENT_LOADS.add(mode)
         masks, spans = _stack_masks(plans)
         S, W = masks.shape
         outs = _common.call(
@@ -128,6 +195,7 @@ def deinterleave(aos: jax.Array, fields: int, *,
             out_specs=out_specs,
         )(jnp.asarray(masks), flat)
     else:
+        SEGMENT_LOADS.add("dynamic")
         outs = _common.call(
             functools.partial(_deint_dyn_kernel, fields=fields),
             out_shape=out_shape,
@@ -135,7 +203,28 @@ def deinterleave(aos: jax.Array, fields: int, *,
             in_specs=[pl.BlockSpec((rt, n), lambda i: (i, 0))],
             out_specs=out_specs,
         )(flat)
-    return [o[:r0].reshape(lead + (m,)) for o in outs]
+    return [o[:r0] for o in outs]
+
+
+def deinterleave(aos: jax.Array, fields: int, *,
+                 fused: bool = True) -> list[jax.Array]:
+    """(..., fields*m) -> fields x (..., m)   (segment load).
+
+    ``fused`` takes the transpose route where
+    :func:`transpose_block_rows` accepts the shape, else the cost-modeled
+    shift plans; ``fused=False`` runs the dynamic networks (the oracle).
+    Each call counts its route in :data:`repro.vx.cache.SEGMENT_LOADS`."""
+    n = aos.shape[-1]
+    assert n % fields == 0
+    flat, lead = _common.flatten_rows(aos)
+    rt = transpose_block_rows(flat.shape[0], n, fields, aos.dtype) \
+        if fused else 0
+    if rt:
+        SEGMENT_LOADS.add("transpose")
+        outs = _deint_transpose(flat, fields, rt)
+    else:
+        outs = _deint_shift(flat, fields, fused)
+    return [o.reshape(lead + (n // fields,)) for o in outs]
 
 
 def deinterleave_many(aos_list: list[jax.Array], fields: int, *,

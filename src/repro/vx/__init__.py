@@ -58,7 +58,7 @@ escalates the shims' DeprecationWarnings to errors).
 from repro.vx import lower, program
 from repro.vx._dispatch import (compact, gather, gather_many, scatter,
                                 scatter_many, transpose, warm)
-from repro.vx.cache import PLANS, PlanCache
+from repro.vx.cache import PLANS, SEGMENT_LOADS, PlanCache
 from repro.vx.policy import (BANK_FIELDS, BANK_STRIDES, IMPLS,
                              MIN_FUSED_ELEMS, Policy, current, resolve, use)
 from repro.vx.program import Program, Shard, Txn
@@ -71,7 +71,7 @@ __all__ = [
     "gather", "scatter", "transpose", "compact", "gather_many",
     "scatter_many", "warm",
     "Policy", "use", "current", "resolve",
-    "PLANS", "PlanCache",
+    "PLANS", "SEGMENT_LOADS", "PlanCache",
     "Shard", "Program", "Txn", "program", "lower",
     "MIN_FUSED_ELEMS", "BANK_STRIDES", "BANK_FIELDS", "IMPLS",
 ]

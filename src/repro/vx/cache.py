@@ -89,6 +89,33 @@ class PlanCache:
 PLANS = PlanCache()
 
 
+class Tally:
+    """Thread-safe counts by name, bumped when a kernel is lowered (at
+    trace time, so a jitted step counts once per compile, not per call)."""
+
+    def __init__(self):
+        self._counts: "collections.Counter[str]" = collections.Counter()
+        self._lock = threading.Lock()
+
+    def add(self, name: str) -> None:
+        with self._lock:
+            self._counts[name] += 1
+
+    def clear(self) -> None:
+        with self._lock:
+            self._counts.clear()
+
+    def stats(self) -> dict:
+        with self._lock:
+            return dict(self._counts)
+
+
+#: Segment loads lowered, by route (``transpose``, ``fused``,
+#: ``per_field``, ``dynamic``; kernels/segment.py): says which shapes the
+#: transpose route engages on.
+SEGMENT_LOADS = Tally()
+
+
 def memoize(kind: str) -> Callable:
     """Decorator replacing per-function ``functools.lru_cache`` for plan
     constructors: entries land in :data:`PLANS` under ``(kind, *args)``.

@@ -27,33 +27,45 @@ def _cfg(layers=4, hd=64, scan=False, impl="pallas", mlp="none", d_ff=0):
 # Scheduler: grouping, one launch, one concatenated mask operand
 # ---------------------------------------------------------------------------
 
+def _fused_split(*xs):
+    # platform_policy off: exercise the merged KERNEL lowering (the TPU
+    # decision) in interpret mode so launches are countable
+    pairs = accessfuse.fuse_deinterleave(list(xs), 2, impl="pallas",
+                                         platform_policy=False)
+    return [f for pair in pairs for f in pair]
+
+
+def _per_access_split(*xs):
+    return [f for x in xs
+            for f in vx.transpose(vx.Segment(n=x.shape[-1], fields=2), x,
+                                  policy="pallas")]
+
+
 def test_scheduler_merges_same_shape_group_into_one_launch():
     # 64*512 = 32768 elements each: above MIN_FUSED_ELEMS, stays pallas
     arrays = [jnp.arange(64 * 512, dtype=jnp.float32).reshape(64, 512) + i
               for i in range(4)]
-
-    def fused(*xs):
-        # platform_policy off: exercise the merged KERNEL lowering (the
-        # TPU decision) in interpret mode so launches are countable
-        pairs = accessfuse.fuse_deinterleave(list(xs), 2, impl="pallas",
-                                             platform_policy=False)
-        return [f for pair in pairs for f in pair]
-
-    def per_access(*xs):
-        return [f for x in xs
-                for f in vx.transpose(vx.Segment(n=x.shape[-1], fields=2),
-                                      x, policy="pallas")]
-
-    lf, mf = accessfuse.jaxpr_access_counts(fused, *arrays)
-    lp, mp = accessfuse.jaxpr_access_counts(per_access, *arrays)
+    lf, mf = accessfuse.jaxpr_access_counts(_fused_split, *arrays)
+    lp, mp = accessfuse.jaxpr_access_counts(_per_access_split, *arrays)
     assert lf == 1 and lp == 4, (lf, lp)
-    assert mf == 1 and mp == 4, (mf, mp)
-    got = jax.jit(fused)(*arrays)
+    # the merged 256 rows are whole 128-row chunks: the transpose route,
+    # which reads no mask; each 64-row access keeps its shift plan's mask
+    assert mf == 0 and mp == 4, (mf, mp)
+    got = jax.jit(_fused_split)(*arrays)
     want = [f for x in arrays
             for f in vx.transpose(vx.Segment(n=x.shape[-1], fields=2), x,
                                   policy="ref")]
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_merged_shift_plan_group_uploads_one_mask():
+    # bfloat16 keeps the shift plans: the merged launch carries the
+    # group's one shared mask operand, the per-access path one each
+    arrays = [jnp.arange(64 * 512, dtype=jnp.bfloat16).reshape(64, 512) + i
+              for i in range(4)]
+    assert accessfuse.jaxpr_access_counts(_fused_split, *arrays) == (1, 1)
+    assert accessfuse.jaxpr_access_counts(_per_access_split, *arrays) == (4, 4)
 
 
 def test_scheduler_inlines_tiny_groups():
@@ -133,7 +145,8 @@ def test_decode_launch_count_regression_gate():
     with accessfuse.pinned_kernel_lowering():
         lf, mf = accessfuse.jaxpr_access_counts(fused, params, cache, tok)
     lp, mp = accessfuse.jaxpr_access_counts(per_access, params, cache, tok)
-    assert lf == 1 and mf == 1, (lf, mf)
+    # the merged split is 1024 float32 rows: the transpose route, no mask
+    assert lf == 1 and mf == 0, (lf, mf)
     assert lp >= 4 and mp >= 4, (lp, mp)
     assert 2 * lf <= lp, (lf, lp)
     assert 2 * mf <= mp, (mf, mp)

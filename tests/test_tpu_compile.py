@@ -6,6 +6,7 @@ and checks that the compiled program launches a Mosaic kernel
 topology is described inside a fixture, never at import: only one process
 may hold the TPU library, and every test worker imports this file.
 """
+import dataclasses
 import os
 
 import jax
@@ -76,12 +77,37 @@ def test_segment_interleave_compiles(for_tpu, one_chip, dtype):
                     half, half) >= 1
 
 
+def test_glu_split_compiles_through_the_shift_plans(for_tpu, one_chip):
+    """The decode step's SwiGLU gate/up split at qwen3-0.6b width: 8 rows,
+    not a whole 128-row chunk, so the shift plans route it."""
+    vx.SEGMENT_LOADS.clear()
+    gu = jax.ShapeDtypeStruct((SLOTS, 6144), jnp.float32, sharding=one_chip)
+    assert _kernels(lambda a: segment.deinterleave(a, 2), gu) >= 1
+    (route,) = vx.SEGMENT_LOADS.stats()
+    assert route in ("fused", "per_field")
+
+
+@pytest.mark.parametrize("n,fields,dtype", [
+    (256, 2, jnp.float32), (256, 2, jnp.int32), (128, 2, jnp.float32),
+    (512, 2, jnp.float32), (384, 3, jnp.float32), (512, 4, jnp.float32),
+    (1024, 8, jnp.float32), (128, 16, jnp.float32), (2048, 2, jnp.float32),
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_transpose_route_compiles(for_tpu, one_chip, n, fields, dtype):
+    """Each kind of (n, fields) the transpose route accepts is one Mosaic
+    kernel for the chip: the transposes and sublane-strided reads lower."""
+    vx.SEGMENT_LOADS.clear()
+    aos = jax.ShapeDtypeStruct((ROWS, n), dtype, sharding=one_chip)
+    assert _kernels(lambda a: segment.deinterleave(a, fields), aos) == 1
+    assert vx.SEGMENT_LOADS.stats() == {"transpose": 1}
+
+
 def test_paged_kv_split_compiles_for_qwen3_pool(for_tpu, one_chip):
     """The decode step's fused FIELD=2 split over every layer of the
-    gathered qwen3-0.6b float32 pool."""
+    gathered qwen3-0.6b float32 pool, through the transpose route."""
     cfg = get_arch("qwen3-0.6b").model
     state = jax.eval_shape(lambda: dec.init_paged_cache(
         cfg, SLOTS, MAX_LEN, PAGE_SIZE, jnp.float32))
+    vx.SEGMENT_LOADS.clear()
     with vx.use("pallas"):
         (gathered,) = jax.eval_shape(
             lambda p, t: kv_interleaved.gather_paged_kv([p], t, PAGE_SIZE),
@@ -90,6 +116,50 @@ def test_paged_kv_split_compiles_for_qwen3_pool(for_tpu, one_chip):
                      jax.ShapeDtypeStruct(gathered.shape, gathered.dtype,
                                           sharding=one_chip))
     assert n >= 1
+    assert vx.SEGMENT_LOADS.stats() == {"transpose": 1}
+
+
+def _serve_programs(cfg, one_chip):
+    """The scheduler's decode step and prefill chunk compiled for the chip
+    under the kernel lowering: ``(text, routes)`` of each, ``routes`` the
+    segment loads its trace lowered (``vx.SEGMENT_LOADS``)."""
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def compiled(lower):
+        vx.SEGMENT_LOADS.clear()
+        text = lower().compile().as_text()
+        return text, vx.SEGMENT_LOADS.stats()
+    params = on_chip(jax.eval_shape(lambda: init_params(
+        cfg, jax.random.key(0))))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    with vx.use("pallas"):
+        sched = Scheduler(cfg, params, slots=SLOTS, max_len=128,
+                          page_size=PAGE_SIZE)
+        state = on_chip(sched.cache.state)
+        step = compiled(lambda: sched._step.lower(
+            params, state,
+            jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((SLOTS,), bool, sharding=one_chip)))
+        chunk = compiled(lambda: sched._chunk.lower(
+            params, state,
+            jax.ShapeDtypeStruct((PAGE_SIZE,), jnp.int32, sharding=one_chip),
+            i32, i32))
+    return step, chunk
+
+
+def _assert_one_named_split(step: str, chunk: str) -> None:
+    def split_names(text):
+        return [line.split(" = ")[0].strip() for line in text.splitlines()
+                if line.strip().startswith(("%kv_split", "ROOT %kv_split"))]
+    assert step.startswith("HloModule jit_decode_step,")
+    (name,) = split_names(step)
+    (call,) = [line for line in step.splitlines()
+               if line.strip().startswith(name + " = ")]
+    assert 'custom_call_target="tpu_custom_call"' in call
+    assert chunk.startswith("HloModule jit_prefill_chunk,")
+    assert split_names(chunk) == []
 
 
 def test_decode_step_and_its_split_carry_stable_names(for_tpu, one_chip):
@@ -100,38 +170,26 @@ def test_decode_step_and_its_split_carry_stable_names(for_tpu, one_chip):
     the names do not depend on them).  The prefill chunk has no
     ``kv_split`` instruction, so the device time of every ``kv_split``
     op is the decode steps' split alone."""
-    cfg = get_arch("qwen3-0.6b").smoke
+    (step, _), (chunk, _) = _serve_programs(get_arch("qwen3-0.6b").smoke,
+                                            one_chip)
+    _assert_one_named_split(step, chunk)
 
-    def on_chip(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
 
-    def split_names(text):
-        return [line.split(" = ")[0].strip() for line in text.splitlines()
-                if line.strip().startswith(("%kv_split", "ROOT %kv_split"))]
-    params = on_chip(jax.eval_shape(lambda: init_params(
-        cfg, jax.random.key(0))))
-    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    with vx.use("pallas"):
-        sched = Scheduler(cfg, params, slots=SLOTS, max_len=128,
-                          page_size=PAGE_SIZE)
-        state = on_chip(sched.cache.state)
-        step = sched._step.lower(
-            params, state,
-            jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip),
-            jax.ShapeDtypeStruct((SLOTS,), bool, sharding=one_chip),
-        ).compile().as_text()
-        chunk = sched._chunk.lower(
-            params, state,
-            jax.ShapeDtypeStruct((PAGE_SIZE,), jnp.int32, sharding=one_chip),
-            i32, i32).compile().as_text()
-    assert step.startswith("HloModule jit_decode_step,")
-    (name,) = split_names(step)
-    (call,) = [line for line in step.splitlines()
-               if line.strip().startswith(name + " = ")]
-    assert 'custom_call_target="tpu_custom_call"' in call
-    assert chunk.startswith("HloModule jit_prefill_chunk,")
-    assert split_names(chunk) == []
+def test_serve_programs_split_kv_through_the_transpose_route(for_tpu,
+                                                            one_chip):
+    """At head_dim 128 (qwen3-0.6b's; two layers of smoke width
+    otherwise) the decode step's whole-step split and the prefill chunk's
+    row split (traced once, in its layer loop) take the transpose route,
+    while the chunk's 16-row splits, SwiGLU gate/up and the fresh K|V
+    beats, keep the shift plans (the step's ride the XLA path).  The
+    split keeps its name: one ``kv_split`` Mosaic call in the step."""
+    cfg = dataclasses.replace(get_arch("qwen3-0.6b").smoke, head_dim=128)
+    (step, step_routes), (chunk, chunk_routes) = _serve_programs(cfg,
+                                                                 one_chip)
+    assert step_routes == {"transpose": 1}
+    assert chunk_routes.pop("transpose") == 1
+    assert set(chunk_routes) <= {"fused", "per_field"} and chunk_routes
+    _assert_one_named_split(step, chunk)
 
 
 @pytest.mark.parametrize("impl", ["pallas", "ref"])

@@ -44,8 +44,8 @@ print("vx.compact agrees:",
 # --- full MoE layer: earth vs argsort dispatch ------------------------------
 for dispatch in ("earth", "sort"):
     spec = MoESpec(n_experts=E, top_k=k, d_ff=64, dispatch=dispatch)
-    y, aux = moe_ffn_local(params["router"], params["wg"], params["wu"],
-                           params["wo"], x, spec, model_axis=None,
-                           data_axes=(), n_shards=1)
+    y, aux, _ = moe_ffn_local(params["router"], params["wg"], params["wu"],
+                              params["wo"], x, spec, model_axis=None,
+                              data_axes=(), n_shards=1)
     print(f"{dispatch:6s}: |y|={float(jnp.linalg.norm(y)):.4f} "
           f"aux={float(aux):.4f}")

@@ -19,12 +19,15 @@ def config() -> ModelConfig:
 
 
 def smoke_config() -> ModelConfig:
+    """One device's share at smoke width: the router spans 16 experts and
+    picks 4, the layer holds experts 0-3 (the CPU tests' held range)."""
     return ModelConfig(
         name="qwen3-moe-smoke", d_model=64, n_layers=2, n_heads=4,
         n_kv_heads=2, head_dim=16, d_ff=64, vocab=512,
         block_pattern=("attn",), moe_pattern=(True,), mlp="swiglu",
-        moe=MoESpec(n_experts=8, top_k=2, d_ff=64), qk_norm=True,
-        tie_embeddings=False)
+        moe=MoESpec(n_experts=16, top_k=4, d_ff=64, held=4,
+                    first_expert=0),
+        qk_norm=True, tie_embeddings=False)
 
 
 def arch() -> ArchConfig:

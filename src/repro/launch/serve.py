@@ -407,7 +407,10 @@ def run(args) -> int:
           f"prefill_chunks={stats['prefill_chunks']} "
           f"decode_steps={stats['decode_steps']} "
           f"host_syncs={stats['host_syncs']} "
-          f"watchdog_breaches={stats.get('watchdog_breaches', 0)}")
+          f"watchdog_breaches={stats.get('watchdog_breaches', 0)}"
+          + (" moe_routed={routed} moe_dropped={dropped} "
+             "moe_per_expert={per_expert}".format(**stats["moe"])
+             if "moe" in stats else ""))
     if "prefix" in stats:
         px = stats["prefix"]
         print(f"prefix cache: hit_rate={px['hit_rate']:.2f} "

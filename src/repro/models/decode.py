@@ -20,6 +20,7 @@ from repro import vx
 from repro.kernels import kv_interleaved
 from repro.models import attention, layers
 from repro.models.ssm import init_mamba_cache, mamba_decode_step
+from repro.models.moe import moe_ffn_local
 from repro.models.transformer import ModelConfig, _ffn_apply
 from repro.models.xlstm import (init_mlstm_state, init_slstm_state,
                                 mlstm_decode_step, slstm_decode_step)
@@ -118,6 +119,13 @@ def init_paged_cache(cfg: ModelConfig, slots: int, max_len: int,
     scales ride the same scan/stack/fork plumbing as the pools, and the
     scale of physical page ``p`` travels with ``p`` through prefix
     adoption and CoW forks for free.  Recurrent leaves stay ``dtype``.
+
+    Models with expert layers also carry ``moe``: int32 counters, summed
+    over layers, of the units the paged prefill chunks and decode steps
+    routed to the held experts (``routed``), dropped over capacity
+    (``dropped``, 0 by construction) and computed per held expert
+    (``per_expert``), accumulated on the device and read by the host only
+    when asked (``Scheduler.stats``).
     """
     ns = cfg.n_superblocks
     n_seq = pages_per_seq(max_len, page_size)
@@ -148,7 +156,7 @@ def init_paged_cache(cfg: ModelConfig, slots: int, max_len: int,
             s = init_slstm_state(slots, cfg.xlstm)
             blocks[f"pos{i}"] = jax.tree.map(
                 lambda a: jnp.broadcast_to(a, (ns,) + a.shape), s)
-    return {
+    state = {
         "pos": jnp.zeros((slots,), jnp.int32),
         "table": jnp.full((slots, n_seq), -1, jnp.int32),
         # descending so pages allocate in 0, 1, 2, ... order
@@ -157,6 +165,12 @@ def init_paged_cache(cfg: ModelConfig, slots: int, max_len: int,
         "ref": jnp.zeros((num_pages,), jnp.int32),
         "blocks": blocks,
     }
+    if any(cfg.moe_pattern):
+        state["moe"] = {"routed": jnp.zeros((), jnp.int32),
+                        "dropped": jnp.zeros((), jnp.int32),
+                        "per_expert": jnp.zeros((cfg.moe.n_held,),
+                                                jnp.int32)}
+    return state
 
 
 def _paged_geometry(cfg: ModelConfig, cache: dict):
@@ -372,8 +386,8 @@ def paged_release_slot(cfg: ModelConfig, cache: dict, slot) -> dict:
             lambda c, s: c.at[:, slot].set(
                 jnp.broadcast_to(s[0], c.shape[2:]).astype(c.dtype)),
             leaf, ini)
-    return {"pos": pos, "table": table, "free": free, "free_top": free_top,
-            "ref": ref, "blocks": blocks}
+    return dict(cache, pos=pos, table=table, free=free, free_top=free_top,
+                ref=ref, blocks=blocks)
 
 
 def paged_insert_prefill(cfg: ModelConfig, cache: dict, slot,
@@ -458,8 +472,8 @@ def paged_insert_prefill(cfg: ModelConfig, cache: dict, slot,
             blocks[f"pos{i}"] = jax.tree.map(
                 lambda c, s: c.at[:, slot].set(s[:, 0].astype(c.dtype)),
                 leaf, st)
-    return {"pos": pos, "table": table, "free": free, "free_top": free_top,
-            "ref": ref, "blocks": blocks}
+    return dict(cache, pos=pos, table=table, free=free, free_top=free_top,
+                ref=ref, blocks=blocks)
 
 
 def paged_adopt_prefix(cfg: ModelConfig, cache: dict, slot,
@@ -484,9 +498,7 @@ def paged_adopt_prefix(cfg: ModelConfig, cache: dict, slot,
     ref = cache["ref"].at[jnp.where(valid, ids, drop)].add(1, mode="drop")
     pos = cache["pos"].at[slot].set(
         jnp.sum(valid.astype(jnp.int32)) * ps)
-    return {"pos": pos, "table": table, "free": cache["free"],
-            "free_top": cache["free_top"], "ref": ref,
-            "blocks": cache["blocks"]}
+    return dict(cache, pos=pos, table=table, ref=ref)
 
 
 def paged_addref(cfg: ModelConfig, cache: dict, page_ids) -> dict:
@@ -574,8 +586,34 @@ def paged_fork_page(cfg: ModelConfig, cache: dict, slot, logical_idx,
     pos = cache["pos"]
     if pos_to is not None:
         pos = pos.at[slot].set(jnp.asarray(pos_to, jnp.int32))
-    return {"pos": pos, "table": table, "free": free,
-            "free_top": free_top, "ref": ref, "blocks": blocks}
+    return dict(cache, pos=pos, table=table, free=free, free_top=free_top,
+                ref=ref, blocks=blocks)
+
+
+def _moe_ffn(p, x, cfg: ModelConfig, valid):
+    """The paged serve path's expert FFN, residual included: x (..., d),
+    ``valid`` of ``x.shape[:-1]``, False for tokens that route nowhere
+    (pad tokens, idle slots).  One device, so the capacity holds every
+    unit and nothing drops.  Returns (x, the layer's units)."""
+    h = layers.rms_norm(x, p["ln2"], cfg.norm_eps)
+    m = p["moe"]
+    y, _, units = moe_ffn_local(
+        m["router"], m["wg"], m["wu"], m["wo"], h.reshape(-1, cfg.d_model),
+        cfg.moe, model_axis=None, data_axes=(), n_shards=1,
+        valid=valid.reshape(-1))
+    return x + y.reshape(x.shape), units
+
+
+def _counted(cache: dict, units: dict) -> dict:
+    """``cache`` with the expert layers' ``units`` (per position, stacked
+    over superblocks) added to its ``moe`` counters."""
+    if not units:
+        return cache
+    moe = dict(cache["moe"])
+    for u in units.values():
+        for name, v in u.items():
+            moe[name] = moe[name] + jnp.sum(v, axis=0)
+    return dict(cache, moe=moe)
 
 
 def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
@@ -588,8 +626,8 @@ def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
     chunk of a serving process shares ONE jit trace and one set of
     compiled access plans; ``count`` (traced) is the number of leading
     tokens that are real.  Pad tokens append nothing (dropped scatter
-    rows), never touch recurrent state, and their garbage activations
-    feed no output.
+    rows), never touch recurrent state, route to no expert, and their
+    garbage activations feed no output.
 
     This is the CANONICAL prefill path for the serving scheduler: every
     page's contents become a deterministic function of (the prefix
@@ -681,7 +719,7 @@ def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
 
     def sb_step(x, inp):
         sb_p, sb_c = inp
-        new_c = {}
+        new_c, units = {}, {}
         for i, kind in enumerate(cfg.block_pattern):
             p = sb_p[f"pos{i}"]
             if kind == "attn":
@@ -745,25 +783,28 @@ def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
                     _slot_state(sb_c[f"pos{i}"]), h, x.dtype)
                 x = x + y
                 new_c[f"pos{i}"] = _put_slot(sb_c[f"pos{i}"], st)
-            if cfg.pos_has_ffn(i):
+            if cfg.pos_has_ffn(i) and cfg.moe_pattern[i]:
+                x, units[f"pos{i}"] = _moe_ffn(p, x, cfg, real[None])
+            elif cfg.pos_has_ffn(i):
                 x, _ = _ffn_apply(p, x, cfg, ctx, i, policy=pol)
-        return x, new_c
+        return x, (new_c, units)
 
     if cfg.scan_layers:
-        _, new_blocks = jax.lax.scan(
+        _, (new_blocks, units) = jax.lax.scan(
             sb_step, x, (params["blocks"], blocks_in))
     else:
         outs = []
         for sbi in range(cfg.n_superblocks):
             sb = jax.tree.map(lambda a: a[sbi], params["blocks"])
             cb = jax.tree.map(lambda a: a[sbi], blocks_in)
-            x, nb = sb_step(x, (sb, cb))
-            outs.append(nb)
-        new_blocks = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+            x, out = sb_step(x, (sb, cb))
+            outs.append(out)
+        new_blocks, units = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
 
     new_pos = pos.at[slot].set(jnp.minimum(start + count, seq))
-    return {"pos": new_pos, "table": table, "free": free,
-            "free_top": free_top, "ref": ref, "blocks": new_blocks}
+    return _counted(dict(cache, pos=new_pos, table=table, free=free,
+                         free_top=free_top, ref=ref, blocks=new_blocks),
+                    units)
 
 
 def paged_truncate(cfg: ModelConfig, cache: dict, new_pos) -> dict:
@@ -791,8 +832,8 @@ def paged_truncate(cfg: ModelConfig, cache: dict, new_pos) -> dict:
     ids = jnp.where(roll, table, -1).reshape(-1)
     ref, free, free_top = _deref_push(cache["ref"], free, free_top, ids)
     table = jnp.where(roll, -1, table)
-    return {"pos": new_pos, "table": table, "free": free,
-            "free_top": free_top, "ref": ref, "blocks": cache["blocks"]}
+    return dict(cache, pos=new_pos, table=table, free=free,
+                free_top=free_top, ref=ref)
 
 
 def paged_verify_step(params, cache: dict, tokens: jax.Array,
@@ -936,7 +977,7 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
 
     def sb_step(x, inp):
         sb_p, sb_c, sb_pre = inp
-        new_c = {}
+        new_c, units = {}, {}
         for i, kind in enumerate(cfg.block_pattern):
             p = sb_p[f"pos{i}"]
             if kind == "attn":
@@ -1010,12 +1051,14 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
                     sb_c[f"pos{i}"], h, x.dtype)
                 x = x + y
                 new_c[f"pos{i}"] = sts
-            if cfg.pos_has_ffn(i):
+            if cfg.pos_has_ffn(i) and cfg.moe_pattern[i]:
+                x, units[f"pos{i}"] = _moe_ffn(p, x, cfg, valid)
+            elif cfg.pos_has_ffn(i):
                 x, _ = _ffn_apply(p, x, cfg, ctx, i, policy=ffn_pol)
-        return x, new_c
+        return x, (new_c, units)
 
     if cfg.scan_layers:
-        x, new_blocks = jax.lax.scan(
+        x, (new_blocks, units) = jax.lax.scan(
             sb_step, x, (params["blocks"], blocks_in, pre_split))
     else:
         outs = []
@@ -1023,9 +1066,9 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
             sb = jax.tree.map(lambda a: a[sbi], params["blocks"])
             cb = jax.tree.map(lambda a: a[sbi], blocks_in)
             pb = jax.tree.map(lambda a: a[sbi], pre_split)
-            x, nb = sb_step(x, (sb, cb, pb))
-            outs.append(nb)
-        new_blocks = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+            x, out = sb_step(x, (sb, cb, pb))
+            outs.append(out)
+        new_blocks, units = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
 
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
@@ -1072,9 +1115,9 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
         ref, free, free_top = _deref_push(ref, free, free_top, ids)
         table = jnp.where(roll, -1, table)
 
-    return logits, out_tok, commit, {
-        "pos": new_pos, "table": table, "free": free,
-        "free_top": free_top, "ref": ref, "blocks": new_blocks}
+    return logits, out_tok, commit, _counted(dict(
+        cache, pos=new_pos, table=table, free=free, free_top=free_top,
+        ref=ref, blocks=new_blocks), units)
 
 
 def paged_decode_step(params, cache: dict, token: jax.Array,
@@ -1086,13 +1129,14 @@ def paged_decode_step(params, cache: dict, token: jax.Array,
 
     Differences from :func:`decode_step` (which remains the dense-cache
     oracle): positions are PER-SLOT (``cache["pos"]``), the step takes an
-    ``active`` mask (idle slots append nothing and advance nothing — the
-    scheduler's active-set batching), appends allocate a page off the
-    device free stack when a slot crosses a page boundary, and attention
-    reads go through ``vx.Paged`` — with ``fuse=True`` ALL layers' page
-    gathers run as ONE fused page-granular program (the table encodes the
-    heterogeneous per-slot lengths; the compiled program is keyed only by
-    page geometry) followed by the usual ONE fused FIELD=2 split.
+    ``active`` mask (idle slots append nothing, advance nothing and route
+    to no expert — the scheduler's active-set batching), appends allocate
+    a page off the device free stack when a slot crosses a page boundary,
+    and attention reads go through ``vx.Paged`` — with ``fuse=True`` ALL
+    layers' page gathers run as ONE fused page-granular program (the table
+    encodes the heterogeneous per-slot lengths; the compiled program is
+    keyed only by page geometry) followed by the usual ONE fused FIELD=2
+    split.
     ``fuse=False`` is the per-access paged oracle.  Sliding-window layers
     mask at attention time instead of ring-overwriting.
 
@@ -1183,7 +1227,7 @@ def paged_decode_step(params, cache: dict, token: jax.Array,
 
     def sb_step(x, inp):
         sb_p, sb_c, sb_pre = inp
-        new_c = {}
+        new_c, units = {}, {}
         for i, kind in enumerate(cfg.block_pattern):
             p = sb_p[f"pos{i}"]
             if kind == "attn":
@@ -1256,14 +1300,16 @@ def paged_decode_step(params, cache: dict, token: jax.Array,
                                           cfg.xlstm)
                 x = x + jnp.where(active[:, None], y, 0)
                 new_c[f"pos{i}"] = _keep_active(st, sb_c[f"pos{i}"], active)
-            if cfg.pos_has_ffn(i):
+            if cfg.pos_has_ffn(i) and cfg.moe_pattern[i]:
+                x, units[f"pos{i}"] = _moe_ffn(p, x, cfg, active)
+            elif cfg.pos_has_ffn(i):
                 x2, _ = _ffn_apply(p, x[:, None], cfg, ctx, i,
                                    policy=ffn_pol)
                 x = x2[:, 0]
-        return x, new_c
+        return x, (new_c, units)
 
     if cfg.scan_layers:
-        x, new_blocks = jax.lax.scan(
+        x, (new_blocks, units) = jax.lax.scan(
             sb_step, x, (params["blocks"], blocks_in, pre_split))
     else:
         outs = []
@@ -1271,17 +1317,17 @@ def paged_decode_step(params, cache: dict, token: jax.Array,
             sb = jax.tree.map(lambda a: a[sbi], params["blocks"])
             cb = jax.tree.map(lambda a: a[sbi], blocks_in)
             pb = jax.tree.map(lambda a: a[sbi], pre_split)
-            x, nb = sb_step(x, (sb, cb, pb))
-            outs.append(nb)
-        new_blocks = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+            x, out = sb_step(x, (sb, cb, pb))
+            outs.append(out)
+        new_blocks, units = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
 
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     logits = layers.unembed(x, head.astype(cfg.cdtype))
     new_pos = pos + (active & (pos < seq)).astype(jnp.int32)
-    return logits, {"pos": new_pos, "table": table, "free": free,
-                    "free_top": free_top, "ref": ref,
-                    "blocks": new_blocks}
+    return logits, _counted(dict(cache, pos=new_pos, table=table, free=free,
+                                 free_top=free_top, ref=ref,
+                                 blocks=new_blocks), units)
 
 
 def decode_step(params, cache: dict, token: jax.Array, cfg: ModelConfig,

@@ -15,6 +15,16 @@ axis, so each device:
 The only collective is the same (T, d) all-reduce a dense TP FFN needs —
 no all-to-all, no (T, E, C) one-hot dispatch tensor (the "crossbar" EARTH
 removes). Token drop only on per-device capacity overflow (slack-bounded).
+
+HELD RANGE: a layer holds the weights of a contiguous range of experts
+(``MoESpec.first_expert``, ``MoESpec.held``) while its router spans all
+``n_experts`` — one device's share of an expert-parallel deployment.  The
+mesh path is the same range cut once more: model-axis device ``i`` holds
+the ``i``-th part of it.  The layer computes only its held experts'
+weighted outputs; what the other experts add lies on other devices.  The
+serve path (``models/decode._moe_ffn``) runs the layer on one device,
+where the capacity holds every unit (dropless by construction), and
+routes masked tokens (idle slots, pad tokens) nowhere.
 """
 from __future__ import annotations
 
@@ -28,20 +38,32 @@ from repro import vx
 
 
 class MoESpec(NamedTuple):
-    n_experts: int
+    n_experts: int            # experts the router spans
     top_k: int
     d_ff: int
     capacity_slack: float = 2.0
     aux_coef: float = 0.01
     dispatch: str = "earth"   # "earth" (shift network) | "sort" (argsort)
+    held: int = 0             # experts whose weights the layer holds (0: all)
+    first_expert: int = 0     # first expert of the held range
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.n_experts
 
 
 def init_moe(key, d_model: int, spec: MoESpec, dtype) -> dict:
+    """Router over all ``n_experts``; expert weights of the held range."""
+    if not 0 <= spec.first_expert <= spec.n_experts - spec.n_held:
+        raise ValueError(f"held experts {spec.first_expert}.."
+                         f"{spec.first_expert + spec.n_held - 1} lie outside "
+                         f"the router's {spec.n_experts}")
     kr, kg, ku, ko = jax.random.split(key, 4)
-    E, f = spec.n_experts, spec.d_ff
+    E, f = spec.n_held, spec.d_ff
     s = d_model ** -0.5
     return {
-        "router": jax.random.normal(kr, (d_model, E), jnp.float32) * s,
+        "router": jax.random.normal(kr, (d_model, spec.n_experts),
+                                    jnp.float32) * s,
         "wg": jax.random.normal(kg, (E, d_model, f), dtype) * s,
         "wu": jax.random.normal(ku, (E, d_model, f), dtype) * s,
         "wo": jax.random.normal(ko, (E, f, d_model), dtype) * f ** -0.5,
@@ -54,30 +76,40 @@ def _capacity(T: int, k: int, n_shards: int, slack: float) -> int:
     return ((cap + 7) // 8) * 8 if cap % 8 else cap
 
 
-def _compact_ids(mine: jax.Array, cap: int, dispatch: str) -> tuple[jax.Array, jax.Array]:
+def _compact_ids(mine: jax.Array, cap: int, dispatch: str) -> jax.Array:
     """Pack indices of set bits of ``mine`` (n,) to the front; take cap."""
     n = mine.shape[0]
     if dispatch == "earth":
         # runtime-count member of the plan bank (core/accessfuse.py):
         # take-masks derived once from the prefix-sum counts, ids pay one
         # shift+select per layer, no conflict reductions
-        packed = vx.compact(vx.Compact(n=n, cap=cap), mine)
-    else:  # argsort baseline (the XLA-native path)
-        order = jnp.argsort(~mine, stable=True)
-        packed = order[:cap].astype(jnp.int32)
-    total = jnp.sum(mine.astype(jnp.int32))
-    pv = jnp.arange(packed.shape[0], dtype=jnp.int32) < total
-    return packed, pv
+        return vx.compact(vx.Compact(n=n, cap=cap), mine)
+    # argsort baseline (the XLA-native path)
+    return jnp.argsort(~mine, stable=True)[:cap].astype(jnp.int32)
 
 
 def moe_ffn_local(router, wg, wu, wo, x, spec: MoESpec, *,
                   model_axis: str | None, data_axes: tuple,
-                  n_shards: int) -> tuple[jax.Array, jax.Array]:
-    """Per-device MoE body. x: (T, d). Returns (y (T, d), aux loss scalar)."""
+                  n_shards: int, valid: jax.Array | None = None
+                  ) -> tuple[jax.Array, jax.Array, dict]:
+    """Per-device MoE body over the ``wg.shape[0]`` experts this device
+    holds, from ``spec.first_expert`` (plus this device's offset on the
+    model axis).  x: (T, d).
+
+    ``valid`` (T,) keeps masked tokens out of routing: they take no
+    capacity and no expert rows.  The buffer of routed units is the
+    mesh-derived capacity with slack, over which units drop; at
+    ``n_shards`` 1 it holds all ``T * top_k`` units, as many as can reach
+    the held experts, so nothing drops.  Returns (y (T, d), aux loss
+    scalar, units): ``units`` holds int32 counts of the units ``routed``
+    to the held experts, those ``dropped`` over capacity, and those
+    computed ``per_expert`` (``(e_loc,)``)."""
     T, d = x.shape
     E, k = spec.n_experts, spec.top_k
-    e_loc = E // n_shards
-    my = jax.lax.axis_index(model_axis) if model_axis else 0
+    e_loc = wg.shape[0]
+    lo = spec.first_expert
+    if model_axis:
+        lo = lo + jax.lax.axis_index(model_axis) * e_loc
 
     logits = (x @ router.astype(x.dtype)).astype(jnp.float32)     # (T, E)
     probs = jax.nn.softmax(logits, axis=-1)
@@ -96,13 +128,17 @@ def moe_ffn_local(router, wg, wu, wo, x, spec: MoESpec, *,
     # ---- unit selection & EARTH compaction ----
     expert = topi.reshape(-1).astype(jnp.int32)                   # (T*k,)
     weight = topw.reshape(-1)
-    mine = (expert >= my * e_loc) & (expert < (my + 1) * e_loc)
+    mine = (expert >= lo) & (expert < lo + e_loc)
+    if valid is not None:
+        mine = mine & jnp.repeat(valid, k)
     cap = _capacity(T, k, n_shards, spec.capacity_slack)
-    packed, pv = _compact_ids(mine, cap, spec.dispatch)           # (cap,)
+    packed = _compact_ids(mine, cap, spec.dispatch)               # (cap,)
+    routed = jnp.sum(mine.astype(jnp.int32))
+    pv = jnp.arange(packed.shape[0], dtype=jnp.int32) < routed
 
     tok = packed // k
     xe = jnp.take(x, tok, axis=0) * pv[:, None].astype(x.dtype)   # (cap, d)
-    le = jnp.take(expert, packed) - my * e_loc
+    le = jnp.take(expert, packed) - lo
     le = jnp.where(pv, le, e_loc)                                 # sentinel
     order = jnp.argsort(le, stable=True)
     xs = jnp.take(xe, order, axis=0)
@@ -124,7 +160,9 @@ def moe_ffn_local(router, wg, wu, wo, x, spec: MoESpec, *,
     contrib = (ye.astype(jnp.float32)
                * w_sorted[:, None]).astype(x.dtype)
     y = jnp.zeros((T, d), x.dtype).at[jnp.take(tok, order)].add(contrib)
-    return y, aux
+    units = {"routed": routed, "dropped": routed - jnp.sum(gs),
+             "per_expert": gs}
+    return y, aux, units
 
 
 def moe_layer(params, x: jax.Array, spec: MoESpec, ctx) -> tuple[jax.Array, jax.Array]:
@@ -141,7 +179,7 @@ def moe_layer(params, x: jax.Array, spec: MoESpec, ctx) -> tuple[jax.Array, jax.
 
     def body(router, wg, wu, wo, xl):
         Tl = xl.shape[0] * xl.shape[1]
-        y, aux = moe_ffn_local(
+        y, aux, _ = moe_ffn_local(
             router, wg, wu, wo, xl.reshape(Tl, d), spec,
             model_axis=m_ax,
             data_axes=ctx.data_axes if ctx else (),

@@ -348,6 +348,7 @@ class Scheduler:
         # or verify steps taken, and device->host reads made (see _sync)
         self.decode_steps = 0
         self.host_syncs = 0
+        self.moe_units: dict[str, np.ndarray] = {}   # folded by stats()
         # per-request latency accounting (host clock, zero device work):
         # TTFT = first decoded token minus submit; inter-token latency is
         # the per-token gap between appends (a K-token speculative commit
@@ -1184,6 +1185,18 @@ class Scheduler:
                    prefill_chunks=self.prefill_chunks,
                    decode_steps=self.decode_steps,
                    host_syncs=self.host_syncs)
+        if "moe" in self.cache.state:
+            # the expert layers' counters: int32 on the device, added to
+            # by every prefill chunk, decode and verify step, read here
+            # only, then folded into host integers and zeroed so that a
+            # long-lived server's counters do not wrap
+            dev = self.cache.state["moe"]
+            for name, v in jax.device_get(dev).items():
+                self.moe_units[name] = self.moe_units.get(name, 0) + (
+                    np.asarray(v, np.int64))
+            self.cache.state = dict(self.cache.state,
+                                    moe=jax.tree.map(jnp.zeros_like, dev))
+            out["moe"] = {k: v.tolist() for k, v in self.moe_units.items()}
         if self.prefix is not None:
             out["prefix"] = self.prefix.stats()
             out["shared_pages"] = int(

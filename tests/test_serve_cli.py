@@ -32,6 +32,21 @@ def test_summary_line_prints_the_serve_path_counters(capsys):
     assert steps == 3 and syncs >= steps + chunks
 
 
+def test_summary_line_prints_the_expert_layer_counters(capsys):
+    """A model with held-range expert layers: the summary line carries
+    the units routed to the held experts, those dropped (none: the serve
+    path is dropless) and those per held expert, which sum to the
+    routed."""
+    args = serve.parse_args(["--arch", "qwen3-moe-30b-a3b"] + SMOKE[2:])
+    assert serve.run(args) == 0
+    m = re.search(r"moe_routed=(\d+) moe_dropped=(\d+) "
+                  r"moe_per_expert=\[([\d, ]+)\]", capsys.readouterr().out)
+    routed, dropped = int(m[1]), int(m[2])
+    per_expert = [int(v) for v in m[3].split(",")]
+    assert routed > 0 and dropped == 0
+    assert len(per_expert) == 4 and sum(per_expert) == routed
+
+
 def test_clean_serve_exits_nonzero_when_tick_cap_hit(capsys, monkeypatch):
     monkeypatch.setattr(serve, "tick_cap", lambda args: 1)
     assert serve.run(serve.parse_args(SMOKE)) == serve.EXIT_UNFINISHED

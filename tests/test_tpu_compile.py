@@ -192,6 +192,24 @@ def test_serve_programs_split_kv_through_the_transpose_route(for_tpu,
     _assert_one_named_split(step, chunk)
 
 
+def test_expert_layer_products_carry_stable_names(for_tpu, one_chip):
+    """A held-range expert model's decode step and prefill chunk run its
+    grouped products as the Mosaic calls ``lax.ragged_dot`` lowers to,
+    named ``ragged-dot-<mode>`` (and their group metadata
+    ``ragged-dot-metadata``): the names a device trace's ``XLA Ops``
+    events carry.  The KV split keeps its one named call in the step."""
+    (step, _), (chunk, _) = _serve_programs(
+        get_arch("qwen3-moe-30b-a3b").smoke, one_chip)
+    _assert_one_named_split(step, chunk)
+    for text in (step, chunk):
+        calls = [line for line in text.splitlines()
+                 if line.strip().startswith("%ragged-dot-")]
+        names = {c.split(" = ")[0].strip().split(".")[0] for c in calls}
+        assert names == {"%ragged-dot-metadata", "%ragged-dot-none"}
+        assert all('custom_call_target="tpu_custom_call"' in c
+                   for c in calls)
+
+
 @pytest.mark.parametrize("impl", ["pallas", "ref"])
 def test_qk_norm_sums_in_fixed_order(for_tpu, one_chip, impl):
     """One prefill chunk's K|V beats at qwen3-0.6b width (split, k-norm,

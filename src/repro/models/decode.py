@@ -24,6 +24,14 @@ from repro.models.moe import moe_ffn_local
 from repro.models.transformer import ModelConfig, _ffn_apply
 from repro.models.xlstm import (init_mlstm_state, init_slstm_state,
                                 mlstm_decode_step, slstm_decode_step)
+from repro.vx.cache import Tally
+
+#: Pool leaves the paged programs write, counted by route when a program
+#: is traced: ``in_place`` — only the new beats scatter into the pool
+#: stack, which XLA then aliases to the donated input; ``scanned`` — the
+#: stack rides the layer scan as an operand and a result, so each layer's
+#: pool is sliced out and written back whole (the ``fuse=False`` oracle).
+POOL_WRITES = Tally()
 
 
 def cache_len_for_pos(cfg: ModelConfig, i: int, max_len: int) -> int:
@@ -616,8 +624,62 @@ def _counted(cache: dict, units: dict) -> dict:
     return dict(cache, moe=moe)
 
 
+def _pool_keys(cache: dict, attn_pos) -> list[str]:
+    """The ``blocks`` keys of the page pool: each attention layer's
+    ``pos{i}`` and, for a quantized pool, its ``scl{i}`` scales."""
+    return [f"{kind}{i}" for i in attn_pos for kind in ("pos", "scl")
+            if f"{kind}{i}" in cache["blocks"]]
+
+
+def _write_beats(spec, blocks: dict, beats: dict, table, pos,
+                 policy) -> dict:
+    """``blocks`` with every attention layer's new beats written into its
+    pool stack in place: ``beats[f"pos{i}"]`` is ``(NS, R, K, 2D)``, and
+    row ``r`` of superblock ``l`` goes to position ``pos[r]`` through
+    ``table[r]`` in that superblock's pages.  One page scatter per pool
+    leaf, the superblock a leading coordinate; a quantized pool widens
+    its ``scl{i}`` scales in the same scatter."""
+    out = dict(blocks)
+    for key, b in beats.items():
+        scl = "scl" + key[3:]
+        new = vx.scatter(spec, blocks[key], b, table=table, pos=pos,
+                         scales=blocks.get(scl), policy=policy)
+        if scl in blocks:
+            new, out[scl] = new
+        out[key] = new
+    return out
+
+
+def _scanned_blocks(cache: dict, blocks: dict, attn_pos,
+                    fuse: bool) -> dict:
+    """The cache leaves that ride the layer scan as operands and results:
+    every leaf on the ``fuse=False`` oracle route, the recurrent ones only
+    when the pool is written in place.  Counts the pool's leaves in
+    :data:`POOL_WRITES` by route."""
+    keys = _pool_keys(cache, attn_pos)
+    for _ in keys:
+        POOL_WRITES.add("in_place" if fuse else "scanned")
+    if not fuse:
+        return blocks
+    return {k: v for k, v in blocks.items() if k not in keys}
+
+
+def _scan_layers(cfg: ModelConfig, sb_step, carry, xs):
+    """``sb_step`` over the superblocks — one scan, or unrolled when
+    ``cfg.scan_layers`` is off: ``(carry, per-superblock results
+    stacked)``."""
+    if cfg.scan_layers:
+        return jax.lax.scan(sb_step, carry, xs)
+    outs = []
+    for sbi in range(cfg.n_superblocks):
+        carry, out = sb_step(carry, jax.tree.map(lambda a: a[sbi], xs))
+        outs.append(out)
+    return carry, jax.tree.map(lambda *ys: jnp.stack(ys), *outs)
+
+
 def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
-                        cfg: ModelConfig, ctx, *, slot, count) -> dict:
+                        cfg: ModelConfig, ctx, *, slot, count,
+                        fuse: bool | None = None) -> dict:
     """Prefill up to ``tokens.shape[0]`` prompt tokens into ONE slot,
     starting at the slot's current position (mid-page starts — e.g.
     after a copy-on-write tail fork — are fine).
@@ -641,12 +703,23 @@ def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
     token-by-token under a scan.  No logits are computed: the scheduler
     feeds the LAST prompt token through the decode step to produce the
     first sampled token (the PR 6 replay cursor), so chunks cover
-    ``prompt[:-1]`` only.  Returns the updated cache."""
+    ``prompt[:-1]`` only.  Returns the updated cache.
+
+    Where the pool is written: attention must read the chunk's own beats
+    through the pool (quantized on write for int8/fp8 pools), so the pool
+    stack rides the layer scan's CARRY.  Each layer scatters its beats
+    into the carried stack at its own superblock (``lead=``) and gathers
+    the slot's row from the same stack; nothing pool-sized is sliced or
+    written back, and XLA aliases the carry to the donated input.
+    ``fuse=False`` (default ``cfg.step_fusion``) is the test oracle: the
+    stack rides the scan as an operand and a result, one layer's pool at
+    a time."""
     from repro.models.transformer import cast_params
     params = cast_params(params, cfg)
     if cfg.encoder is not None:
         raise NotImplementedError("paged serving covers decoder-only "
                                   "models; use encdec.decode_step")
+    fuse = cfg.step_fusion if fuse is None else fuse
     pol = cfg.vx_policy
     C = tokens.shape[0]
     slot = jnp.asarray(slot, jnp.int32)
@@ -691,6 +764,9 @@ def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
         table_c = jnp.broadcast_to(row, (C, n_seq))
         wpos = jnp.where(real & (tpos < seq), tpos, -1)
         spec = vx.Paged(page_size=ps, pages=n_seq, trail=2)
+    scanned = _scanned_blocks(cache, blocks_in, attn_pos, fuse)
+    # in place, the pool stack rides the scan's carry
+    pools = {k: v for k, v in blocks_in.items() if k not in scanned}
 
     x = layers.embed(tokens, params["embed"]).astype(cfg.cdtype)[None]
 
@@ -717,8 +793,10 @@ def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
                 full, s1.astype(full.dtype), slot, axis=0),
             sb_state, new1)
 
-    def sb_step(x, inp):
-        sb_p, sb_c = inp
+    def sb_step(carry, inp):
+        x, pools = carry
+        sb_p, sb_c, layer = inp
+        pools = dict(pools)
         new_c, units = {}, {}
         for i, kind in enumerate(cfg.block_pattern):
             p = sb_p[f"pos{i}"]
@@ -727,24 +805,23 @@ def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
                 q, k, v, kv = attention.qkv_project(
                     p["attn"], h, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
                     tpos[None], cfg.rope_theta, policy=pol)
-                pool = sb_c[f"pos{i}"]           # (P, ps, K, 2D)
+                # in place: this superblock of the carried stack;
+                # oracle: the layer's own pool (P, ps, K, 2D)
+                src, dst, lead = ((pools, pools, layer) if fuse
+                                  else (sb_c, new_c, None))
+                # quantize-on-write (scale widens monotonically), then
+                # the attention read dequantizes the slot's whole prefix
+                # — including the beats just written — in the same
+                # one-program gather
+                pool = vx.scatter(spec, src[f"pos{i}"], kv[0], table=table_c,
+                                  pos=wpos, scales=src.get(f"scl{i}"),
+                                  lead=lead, policy=pol)
                 if quantized:
-                    # quantize-on-write (scale widens monotonically),
-                    # then the attention read dequantizes the slot's
-                    # whole prefix — including the beats just written —
-                    # in the same one-program gather
-                    pool, scl = vx.scatter(spec, pool, kv[0],
-                                           table=table_c, pos=wpos,
-                                           scales=sb_c[f"scl{i}"],
-                                           policy=pol)
-                    full = vx.gather(spec, pool, table=row[None],
-                                     scales=scl, policy=pol)
-                    new_c[f"scl{i}"] = scl
-                else:
-                    pool = vx.scatter(spec, pool, kv[0], table=table_c,
-                                      pos=wpos, policy=pol)
-                    full = vx.gather(spec, pool, table=row[None],
-                                     policy=pol)
+                    pool, dst[f"scl{i}"] = pool
+                full = vx.gather(spec, pool, table=row[None],
+                                 scales=dst.get(f"scl{i}"), lead=lead,
+                                 policy=pol)
+                dst[f"pos{i}"] = pool
                 k_all, v_all = vx.transpose(
                     vx.Segment(n=full.shape[-1], fields=2), full,
                     policy=pol)
@@ -752,7 +829,6 @@ def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
                     q, k_all, v_all, tpos, window=cfg.window_pattern[i])
                 x = x + (out.reshape(1, C, cfg.n_heads * cfg.hd)
                          @ p["attn"]["wo"]).astype(x.dtype)
-                new_c[f"pos{i}"] = pool
             elif kind == "mamba":
                 h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
                 pm = dict(p["mamba"])
@@ -787,23 +863,15 @@ def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
                 x, units[f"pos{i}"] = _moe_ffn(p, x, cfg, real[None])
             elif cfg.pos_has_ffn(i):
                 x, _ = _ffn_apply(p, x, cfg, ctx, i, policy=pol)
-        return x, (new_c, units)
+        return (x, pools), (new_c, units)
 
-    if cfg.scan_layers:
-        _, (new_blocks, units) = jax.lax.scan(
-            sb_step, x, (params["blocks"], blocks_in))
-    else:
-        outs = []
-        for sbi in range(cfg.n_superblocks):
-            sb = jax.tree.map(lambda a: a[sbi], params["blocks"])
-            cb = jax.tree.map(lambda a: a[sbi], blocks_in)
-            x, out = sb_step(x, (sb, cb))
-            outs.append(out)
-        new_blocks, units = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-
+    (_, pools), (new_blocks, units) = _scan_layers(
+        cfg, sb_step, (x, pools),
+        (params["blocks"], scanned, jnp.arange(cfg.n_superblocks)))
+    blocks = dict(blocks_in, **new_blocks, **pools)
     new_pos = pos.at[slot].set(jnp.minimum(start + count, seq))
     return _counted(dict(cache, pos=new_pos, table=table, free=free,
-                         free_top=free_top, ref=ref, blocks=new_blocks),
+                         free_top=free_top, ref=ref, blocks=blocks),
                     units)
 
 
@@ -864,6 +932,12 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
     beats are inserted as floats (a scatter, not a gather), so fused /
     per-access / quantized arms all see bit-identical attention inputs,
     and float pools match the single-token oracle bitwise.
+
+    Where the pool is written: as in :func:`paged_decode_step`, the fused
+    loop never reads the pool; each layer returns its ``B*K`` fresh
+    beats, and after the loop one page scatter per pool leaf writes them
+    all into the donated stack in place.  The ``fuse=False`` oracle scans
+    the stack one layer's pool at a time.
 
     Rejection rolls back through the page table ONLY: pages allocated
     this step at logical indices past the accept extent are guaranteed
@@ -959,6 +1033,7 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
                     if quantized else None))
         splits = kv_interleaved.split_kv_step(gathered, policy=pol)
         pre_split = {f"pos{i}": splits[a] for a, i in enumerate(attn_pos)}
+    scanned = _scanned_blocks(cache, blocks_in, attn_pos, fuse)
     beat_pol = (pol.for_elems(B * K * cfg.n_kv_heads * 2 * cfg.hd)
                 if fuse else pol)
     ffn_pol = pol.for_elems(B * K * 2 * cfg.d_ff) if fuse else pol
@@ -985,31 +1060,28 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
                 q, k, v, kv = attention.qkv_project(
                     p["attn"], h, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
                     tpos, cfg.rope_theta, policy=beat_pol)
-                pool = sb_c[f"pos{i}"]               # (P, ps, Kh, 2D)
-                if not fuse:
+                kv_flat = kv.reshape(B * K, cfg.n_kv_heads, 2 * cfg.hd)
+                if fuse:
+                    # the fresh beats, written into the stack after the
+                    # loop
+                    new_c[f"pos{i}"] = kv_flat
+                    k_pre, v_pre = sb_pre[f"pos{i}"]
+                else:
+                    pool = sb_c[f"pos{i}"]           # (P, ps, Kh, 2D)
+                    scl = sb_c.get(f"scl{i}")
                     # per-access oracle reads PRE-append too (then the
                     # fresh-beat insert below), so every arm sees
                     # bit-identical attention inputs
-                    full = vx.gather(
-                        spec, pool, table=table,
-                        scales=(sb_c[f"scl{i}"] if quantized else None),
-                        policy=pol, shard=pool_shard)  # (B, S, Kh, 2D)
-                    pre = vx.transpose(
+                    full = vx.gather(spec, pool, table=table, scales=scl,
+                                     policy=pol, shard=pool_shard)
+                    k_pre, v_pre = vx.transpose(
                         vx.Segment(n=full.shape[-1], fields=2), full,
                         policy=pol)
-                kv_flat = kv.reshape(B * K, cfg.n_kv_heads, 2 * cfg.hd)
-                if quantized:
-                    pool, scl = vx.scatter(spec, pool, kv_flat,
-                                           table=table_flat,
-                                           pos=wpos_flat,
-                                           scales=sb_c[f"scl{i}"],
-                                           policy=pol)
-                    new_c[f"scl{i}"] = scl
-                else:
-                    pool = vx.scatter(spec, pool, kv_flat,
-                                      table=table_flat, pos=wpos_flat,
-                                      policy=pol)
-                k_pre, v_pre = (sb_pre[f"pos{i}"] if fuse else pre)
+                    pool = vx.scatter(spec, pool, kv_flat, table=table_flat,
+                                      pos=wpos_flat, scales=scl, policy=pol)
+                    if quantized:
+                        pool, new_c[f"scl{i}"] = pool
+                    new_c[f"pos{i}"] = pool
                 # insert ALL K fresh beats as floats (a scatter eqn —
                 # the gather gate stays at one); rows past n_draft drop
                 k_all = k_pre.at[b_idx, wp_ins].set(
@@ -1020,7 +1092,6 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
                     q, k_all, v_all, qpos, window=cfg.window_pattern[i])
                 x = x + (out.reshape(B, K, cfg.n_heads * cfg.hd)
                          @ p["attn"]["wo"]).astype(x.dtype)
-                new_c[f"pos{i}"] = pool
             elif kind == "mamba":
                 h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
                 pm = dict(p["mamba"])
@@ -1057,18 +1128,12 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
                 x, _ = _ffn_apply(p, x, cfg, ctx, i, policy=ffn_pol)
         return x, (new_c, units)
 
-    if cfg.scan_layers:
-        x, (new_blocks, units) = jax.lax.scan(
-            sb_step, x, (params["blocks"], blocks_in, pre_split))
-    else:
-        outs = []
-        for sbi in range(cfg.n_superblocks):
-            sb = jax.tree.map(lambda a: a[sbi], params["blocks"])
-            cb = jax.tree.map(lambda a: a[sbi], blocks_in)
-            pb = jax.tree.map(lambda a: a[sbi], pre_split)
-            x, out = sb_step(x, (sb, cb, pb))
-            outs.append(out)
-        new_blocks, units = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+    x, (new_blocks, units) = _scan_layers(
+        cfg, sb_step, x, (params["blocks"], scanned, pre_split))
+    beats = ({f"pos{i}": new_blocks.pop(f"pos{i}") for i in attn_pos}
+             if fuse else {})
+    blocks = _write_beats(spec, dict(blocks_in, **new_blocks), beats,
+                          table_flat, wpos_flat, pol)
 
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
@@ -1099,11 +1164,9 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
             out = jnp.where(mkk, a[:, kk], out)
         return out
 
-    fixed = dict(new_blocks)
     for i, kind in enumerate(cfg.block_pattern):
         if kind != "attn":
-            fixed[f"pos{i}"] = jax.tree.map(_sel, new_blocks[f"pos{i}"])
-    new_blocks = fixed
+            blocks[f"pos{i}"] = jax.tree.map(_sel, new_blocks[f"pos{i}"])
 
     if attn_pos:
         # rollback via the page table only: pages allocated THIS step at
@@ -1117,7 +1180,7 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
 
     return logits, out_tok, commit, _counted(dict(
         cache, pos=new_pos, table=table, free=free, free_top=free_top,
-        ref=ref, blocks=new_blocks), units)
+        ref=ref, blocks=blocks), units)
 
 
 def paged_decode_step(params, cache: dict, token: jax.Array,
@@ -1139,6 +1202,16 @@ def paged_decode_step(params, cache: dict, token: jax.Array,
     split.
     ``fuse=False`` is the per-access paged oracle.  Sliding-window layers
     mask at attention time instead of ring-overwriting.
+
+    Where the pool is written: attention reads the pre-append pages from
+    that one gather plus the fresh beat, so the layer loop never reads the
+    pool.  Each layer returns its fresh beats ``(B, K, 2D)`` as a small
+    scan result, and after the loop ONE page scatter per pool leaf writes
+    every superblock's beats into the donated stack in place, the
+    superblock a leading scatter coordinate (``-1`` and out-of-range rows
+    drop; a pool sharded on its page axis stays sharded).  The
+    ``fuse=False`` oracle instead scans the stack, scattering and reading
+    back one layer's pool at a time.
 
     ``pool_shard`` (a ``vx.Shard`` on the pool page axis, ``axis=-4``)
     lowers every page gather shard-locally — the pool, sharded over the
@@ -1220,6 +1293,7 @@ def paged_decode_step(params, cache: dict, token: jax.Array,
                     if quantized else None))
         splits = kv_interleaved.split_kv_step(gathered, policy=pol)
         pre_split = {f"pos{i}": splits[a] for a, i in enumerate(attn_pos)}
+    scanned = _scanned_blocks(cache, blocks_in, attn_pos, fuse)
     beat_pol = (pol.for_elems(B * cfg.n_kv_heads * 2 * cfg.hd)
                 if fuse else pol)
     ffn_pol = pol.for_elems(B * 2 * cfg.d_ff) if fuse else pol
@@ -1235,31 +1309,33 @@ def paged_decode_step(params, cache: dict, token: jax.Array,
                 q, k, v, kv = attention.qkv_project(
                     p["attn"], h[:, None], cfg.n_heads, cfg.n_kv_heads,
                     cfg.hd, pos[:, None], cfg.rope_theta, policy=beat_pol)
-                pool = sb_c[f"pos{i}"]                 # (P, ps, K, 2D)
-                if quantized and not fuse:
-                    # per-access quantized arm reads PRE-append (like
-                    # the fused pre-gather) and inserts the fresh FLOAT
-                    # beat below — attention then sees bit-identical
-                    # inputs on both paths (the appended beat is only
-                    # quantized for the NEXT step's read, exactly as in
-                    # the fused arm)
-                    full = vx.gather(spec, pool, table=table,
-                                     scales=sb_c[f"scl{i}"], policy=pol,
-                                     shard=pool_shard)  # (B, S, K, 2D)
-                    pre = vx.transpose(
-                        vx.Segment(n=full.shape[-1], fields=2), full,
-                        policy=pol)
-                if quantized:
-                    pool, scl = vx.scatter(spec, pool, kv[:, 0],
-                                           table=table, pos=write_pos,
-                                           scales=sb_c[f"scl{i}"],
-                                           policy=pol)
-                    new_c[f"scl{i}"] = scl
+                if fuse:
+                    # the fresh beat, written into the stack after the loop
+                    new_c[f"pos{i}"] = kv[:, 0]
+                    pre = sb_pre[f"pos{i}"]
                 else:
+                    pool = sb_c[f"pos{i}"]             # (P, ps, K, 2D)
+                    scl = sb_c.get(f"scl{i}")
+                    if quantized:
+                        # per-access quantized arm reads PRE-append (like
+                        # the fused pre-gather) and inserts the fresh
+                        # FLOAT beat below — attention then sees
+                        # bit-identical inputs on both paths (the
+                        # appended beat is only quantized for the NEXT
+                        # step's read, exactly as in the fused arm)
+                        full = vx.gather(spec, pool, table=table,
+                                         scales=scl, policy=pol,
+                                         shard=pool_shard)
+                        pre = vx.transpose(
+                            vx.Segment(n=full.shape[-1], fields=2), full,
+                            policy=pol)
                     pool = vx.scatter(spec, pool, kv[:, 0], table=table,
-                                      pos=write_pos, policy=pol)
+                                      pos=write_pos, scales=scl, policy=pol)
+                    if quantized:
+                        pool, new_c[f"scl{i}"] = pool
+                    new_c[f"pos{i}"] = pool
                 if fuse or quantized:
-                    k_pre, v_pre = (sb_pre[f"pos{i}"] if fuse else pre)
+                    k_pre, v_pre = pre
                     ins = (active[:, None]
                            & (jnp.arange(seq)[None, :] == pos[:, None]))
                     ins = ins[:, :, None, None]
@@ -1277,7 +1353,6 @@ def paged_decode_step(params, cache: dict, token: jax.Array,
                     window=cfg.window_pattern[i])
                 x = x + (out.reshape(B, cfg.n_heads * cfg.hd)
                          @ p["attn"]["wo"]).astype(x.dtype)
-                new_c[f"pos{i}"] = pool
             elif kind == "mamba":
                 h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
                 pm = dict(p["mamba"])
@@ -1308,18 +1383,12 @@ def paged_decode_step(params, cache: dict, token: jax.Array,
                 x = x2[:, 0]
         return x, (new_c, units)
 
-    if cfg.scan_layers:
-        x, (new_blocks, units) = jax.lax.scan(
-            sb_step, x, (params["blocks"], blocks_in, pre_split))
-    else:
-        outs = []
-        for sbi in range(cfg.n_superblocks):
-            sb = jax.tree.map(lambda a: a[sbi], params["blocks"])
-            cb = jax.tree.map(lambda a: a[sbi], blocks_in)
-            pb = jax.tree.map(lambda a: a[sbi], pre_split)
-            x, out = sb_step(x, (sb, cb, pb))
-            outs.append(out)
-        new_blocks, units = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+    x, (new_blocks, units) = _scan_layers(
+        cfg, sb_step, x, (params["blocks"], scanned, pre_split))
+    beats = ({f"pos{i}": new_blocks.pop(f"pos{i}") for i in attn_pos}
+             if fuse else {})
+    blocks = _write_beats(spec, dict(blocks_in, **new_blocks), beats,
+                          table, write_pos, pol)
 
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
@@ -1327,7 +1396,7 @@ def paged_decode_step(params, cache: dict, token: jax.Array,
     new_pos = pos + (active & (pos < seq)).astype(jnp.int32)
     return logits, _counted(dict(cache, pos=new_pos, table=table, free=free,
                                  free_top=free_top, ref=ref,
-                                 blocks=new_blocks), units)
+                                 blocks=blocks), units)
 
 
 def decode_step(params, cache: dict, token: jax.Array, cfg: ModelConfig,

@@ -93,7 +93,7 @@ def _bind_scales(spec: Paged, scales):
 
 
 def gather(spec: AccessSpec, buf: jax.Array, *, stride=None, shift=None,
-           valid=None, table=None, scales=None,
+           valid=None, table=None, scales=None, lead=None,
            policy: Policy | str | None = None,
            shard: Shard | None = None) -> jax.Array:
     """Dense read through the access described by ``spec``.
@@ -114,7 +114,9 @@ def gather(spec: AccessSpec, buf: jax.Array, *, stride=None, shift=None,
       shard-locally from the owned page block and psum-merges — the
       sharded pool is never sliced globally.  A QUANTIZED pool passes
       its per-page scale tensor as ``scales=`` and returns dequantized
-      float sequences from the same one-program gather.
+      float sequences from the same one-program gather.  ``lead=`` (a
+      runtime index into the single lead axis of a pool stacked over
+      layers) reads that one layer's pages from the stack in place.
 
     For the other specs ``shard=`` marks ``buf``'s lane axis as sharded:
     the access lowers to shard-local offset-rebased plans under
@@ -134,11 +136,13 @@ def gather(spec: AccessSpec, buf: jax.Array, *, stride=None, shift=None,
         if table is None:
             raise ValueError("Paged gather needs the page table as table=")
         spec = _bind_scales(spec.bind(buf.dtype), scales)
-        if spec.quantized:
-            return _lower.run("paged.gather", spec, pol.impl,
-                              buf, scales, table, shard=shard)
-        return _lower.run("paged.gather", spec, pol.impl,
-                          buf, table, shard=shard)
+        ops = (buf, scales, table) if spec.quantized else (buf, table)
+        if lead is not None:
+            if shard is not None:
+                raise NotImplementedError("lead= with shard=")
+            ops += (lead,)
+        return _lower.run("paged.gather", spec, pol.impl, *ops,
+                          shard=shard)
     if isinstance(spec, Indexed):
         spec = spec.bind(buf.dtype)
         static = _fold_routing(spec, shift, valid)
@@ -155,7 +159,7 @@ def gather(spec: AccessSpec, buf: jax.Array, *, stride=None, shift=None,
 
 def scatter(spec: AccessSpec, buf: jax.Array, values: jax.Array, *,
             stride=None, shift=None, valid=None, table=None, pos=None,
-            scales=None, policy: Policy | str | None = None,
+            scales=None, lead=None, policy: Policy | str | None = None,
             shard: Shard | None = None):
     """Write/merge through the access described by ``spec``.
 
@@ -169,7 +173,10 @@ def scatter(spec: AccessSpec, buf: jax.Array, values: jax.Array, *,
       unallocated page entry are dropped); returns the updated pool.
       A QUANTIZED pool passes ``scales=`` and gets ``(pool, scales)``
       back — the beat quantizes on write and the page scale widens
-      monotonically (see vx/lower.py).
+      monotonically (see vx/lower.py).  A pool stacked over layers takes
+      one beat per row for every layer, or ``(*lead, *batch, *trail)``
+      values, each layer's own; ``lead=`` (a runtime layer index) writes
+      that one layer of the stack in place.
     * :class:`Indexed` — raw DROM scatter of ``values`` (``buf`` is unused;
       pass None); returns ``(payload, occupancy)``.
     * :class:`Compact` — expansion (the compaction inverse): ``buf`` is the
@@ -181,11 +188,10 @@ def scatter(spec: AccessSpec, buf: jax.Array, values: jax.Array, *,
         if table is None or pos is None:
             raise ValueError("Paged scatter needs table= and pos=")
         spec = _bind_scales(spec.bind(buf.dtype), scales)
-        if spec.quantized:
-            return _lower.run("paged.scatter", spec, pol.impl,
-                              buf, scales, values, table, pos, shard=shard)
-        return _lower.run("paged.scatter", spec, pol.impl,
-                          buf, values, table, pos, shard=shard)
+        ops = (buf, scales) if spec.quantized else (buf,)
+        ops += (values, table, pos) + (() if lead is None else (lead,))
+        return _lower.run("paged.scatter", spec, pol.impl, *ops,
+                          shard=shard)
     if isinstance(spec, Strided):
         spec = spec.bind(buf.dtype)
         static = _static_strided(spec, stride)

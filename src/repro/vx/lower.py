@@ -291,6 +291,24 @@ def _compact_ids(txn: prg.Txn, specs: tuple):
     return lambda mask: accessfuse.compact_indices(mask, cap)
 
 
+def _lead_index(spec, pool, lead, batch_ndim: int) -> tuple:
+    """Index prefix over a paged pool's lead dims for ``batch_ndim`` rows
+    of page ids: with ``lead`` (a runtime index into the single lead axis
+    of a stacked pool) that one entry, else every lead entry.  The lead
+    axes are index coordinates, never part of the written window: a
+    window that spans them makes XLA lay the whole pool out again around
+    the scatter, while a window of one beat keeps the write in place."""
+    pa = spec.pool_axis(pool.ndim)
+    if lead is not None:
+        if pa != 1:
+            raise ValueError(f"lead= indexes one lead axis; the pool "
+                             f"{pool.shape} has {pa}")
+        return (lead,)
+    return tuple(jnp.arange(n).reshape((1,) * d + (n,)
+                                       + (1,) * (pa - 1 - d + batch_ndim))
+                 for d, n in enumerate(pool.shape[:pa]))
+
+
 def _paged_gather(txn: prg.Txn, specs: tuple):
     """Page-table gather: ``out[.., j, ..] = pool[.., t[j//ps], j%ps, ..]``.
 
@@ -311,12 +329,24 @@ def _paged_gather(txn: prg.Txn, specs: tuple):
     int page beats before the validity mask.  Masking AFTER the multiply
     matters for fp8: garbage on never-written pages can be NaN and
     ``0 * NaN`` would leak through a pre-mask.
+
+    A runtime ``lead`` index reads one entry of a stacked pool's lead
+    axis (one layer of a pool stack) without slicing the stack: the take
+    indexes the stack directly, and the output has no lead dims.
     """
     spec = specs[0]
     ps, pages, trail = spec.page_size, spec.pages, spec.trail
 
+    def take_pages(pool, table, lead):
+        ids = jnp.maximum(table, 0)
+        if lead is None:
+            pa = spec.pool_axis(pool.ndim)
+            return jnp.take(pool, ids, axis=pa), pa
+        pre = _lead_index(spec, pool, lead, table.ndim)
+        return pool.at[pre + (ids,)].get(mode="fill"), 0
+
     if spec.quantized:
-        def qfn(pool, scales, table):
+        def qfn(pool, scales, table, lead=None):
             pa = spec.pool_axis(pool.ndim)
             if pool.shape[pa + 1] != ps:
                 raise ValueError(
@@ -334,7 +364,9 @@ def _paged_gather(txn: prg.Txn, specs: tuple):
                     f"per trail dim except the last) for pool "
                     f"{pool.shape}")
             valid = table >= 0
-            ints = jnp.take(pool, jnp.maximum(table, 0), axis=pa)
+            ints, pa = take_pages(pool, table, lead)
+            if lead is not None:
+                scales = scales[lead]
             # one-hot scale lookup: (*batch, pages, P) @ (P, *lead, *th)
             oh = (table[..., None] == jnp.arange(P)).astype(scales.dtype)
             s = jnp.tensordot(oh, jnp.moveaxis(scales, pa, 0), axes=1)
@@ -355,7 +387,7 @@ def _paged_gather(txn: prg.Txn, specs: tuple):
 
         return qfn
 
-    def fn(pool, table):
+    def fn(pool, table, lead=None):
         pa = spec.pool_axis(pool.ndim)
         if pool.shape[pa + 1] != ps:
             raise ValueError(
@@ -365,7 +397,7 @@ def _paged_gather(txn: prg.Txn, specs: tuple):
             raise ValueError(
                 f"table has {table.shape[-1]} pages, spec.pages is {pages}")
         valid = table >= 0
-        out = jnp.take(pool, jnp.maximum(table, 0), axis=pa)
+        out, pa = take_pages(pool, table, lead)
         # out: (*lead, *batch, pages, ps, *trail); zero unallocated pages
         vshape = ((1,) * pa + table.shape + (1,) + (1,) * trail)
         out = jnp.where(valid.reshape(vshape), out, jnp.zeros_like(out))
@@ -398,27 +430,28 @@ def _paged_scatter(txn: prg.Txn, specs: tuple):
     3. quantize each beat at the final page scale and write it at its
        distinct ``(page, offset)`` — exactly the float arm's pattern.
 
-    Returns ``(pool, scales)``."""
+    Returns ``(pool, scales)``.
+
+    A pool with lead dims (a stack over layers) takes one beat per row
+    broadcast over every lead entry, or one per lead entry and row
+    (``values`` of ``(*lead, *batch, *trail)``: each layer's own beats in
+    one scatter).  A runtime ``lead`` index writes one entry of a stacked
+    pool's lead axis in place."""
     spec = specs[0]
     ps, trail = spec.page_size, spec.trail
 
     if spec.quantized:
         from repro.core import quant
 
-        def qfn(pool, scales, values, table, pos):
-            pa = spec.pool_axis(pool.ndim)
-            if pa != 0:
-                raise NotImplementedError(
-                    "quantized paged scatter wants the page axis leading "
-                    "(no lead dims): per-lead beat scales have no "
-                    "broadcast rule here")
+        def qfn(pool, scales, values, table, pos, lead=None):
             if trail < 1:
                 raise NotImplementedError(
                     "quantized paged scatter needs >= 1 trailing dim "
                     "(the max-abs scale reduces over the last)")
-            P = pool.shape[0]
+            P = pool.shape[spec.pool_axis(pool.ndim)]
             qm = quant.qmax(pool.dtype)
             pos = jnp.asarray(pos, jnp.int32)
+            pre = _lead_index(spec, pool, lead, pos.ndim)
             oob = (pos < 0) | (pos >= spec.pages * ps)
             page = jnp.where(oob, 0, pos // ps)
             phys = jnp.take_along_axis(table, page[..., None],
@@ -427,45 +460,46 @@ def _paged_scatter(txn: prg.Txn, specs: tuple):
             physd = jnp.where(drop, P, phys)     # out of bounds -> dropped
             off = jnp.where(drop, ps, pos % ps)
             safe = jnp.clip(phys, 0, P - 1)      # reads for dropped rows
-            # 1. widen: beat scale per (*batch, *trail[:-1])
+            # 1. widen: beat scale per (*lead, *batch, *trail[:-1])
             s_beat = jnp.max(jnp.abs(values), axis=-1) / qm
-            s_old = jnp.take(scales, safe, axis=0)
-            scales = scales.at[physd].max(jnp.maximum(s_old, s_beat),
-                                          mode="drop")
-            s_fin = jnp.take(scales, safe, axis=0)
+            s_old = scales[pre + (safe,)]
+            scales = scales.at[pre + (physd,)].max(
+                jnp.maximum(s_old, s_beat), mode="drop")
+            s_fin = scales[pre + (safe,)]
             # 2. rescale resident ints to the widened scale
             ratio = jnp.where(s_fin > 0,
                               s_old / jnp.where(s_fin > 0, s_fin, 1.0),
                               1.0)
-            rb = jnp.expand_dims(ratio, pos.ndim)[..., None]
-            pgs = jnp.take(pool, safe, axis=0)
-            pool = pool.at[physd].set(
+            rb = jnp.expand_dims(ratio, ratio.ndim - trail + 1)[..., None]
+            pgs = pool[pre + (safe,)]
+            pool = pool.at[pre + (physd,)].set(
                 quant.requantize(pgs.astype(rb.dtype) * rb, pool.dtype),
                 mode="drop")
             # 3. quantize the beat at the final page scale (safe divide:
             # an all-zero beat on a fresh page keeps scale 0 and writes
             # 0 — never NaN, fp8 has NaN encodings)
             qb = quant.quantize(values, s_fin[..., None], pool.dtype)
-            pool = pool.at[(physd, off)].set(qb, mode="drop")
+            pool = pool.at[pre + (physd, off)].set(qb, mode="drop")
             return pool, scales
 
         return qfn
 
-    def fn(pool, values, table, pos):
+    def fn(pool, values, table, pos, lead=None):
         pa = spec.pool_axis(pool.ndim)
         P = pool.shape[pa]
         pos = jnp.asarray(pos, jnp.int32)
+        pre = _lead_index(spec, pool, lead, pos.ndim)
         oob = (pos < 0) | (pos >= spec.pages * ps)
         page = jnp.where(oob, 0, pos // ps)
         phys = jnp.take_along_axis(table, page[..., None], axis=-1)[..., 0]
         drop = oob | (phys < 0)
         phys = jnp.where(drop, P, phys)          # out of bounds -> dropped
         off = jnp.where(drop, ps, pos % ps)
-        idx = (slice(None),) * pa + (phys, off)
-        vals = values.astype(pool.dtype).reshape(
-            (1,) * pa + values.shape)
-        vals = jnp.broadcast_to(vals, pool.shape[:pa] + values.shape)
-        return pool.at[idx].set(vals, mode="drop")
+        vals = values.astype(pool.dtype)
+        if lead is None:     # per lead entry, or broadcast over them
+            rows = values.shape[values.ndim - pos.ndim - trail:]
+            vals = jnp.broadcast_to(vals, pool.shape[:pa] + rows)
+        return pool.at[pre + (phys, off)].set(vals, mode="drop")
 
     return fn
 

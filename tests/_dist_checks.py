@@ -8,6 +8,7 @@ Each check prints CHECK_OK on success.
 from __future__ import annotations
 
 import os
+import re
 import sys
 
 if "--xla_force_host_platform_device_count" not in os.environ.get(
@@ -383,6 +384,32 @@ def check_paged_pool_shard():
         ls, shd = jshd(params, shd, tok)
         np.testing.assert_array_equal(np.asarray(lr), np.asarray(ls))
         tok = jnp.argmax(lr.astype(jnp.float32), -1).astype(jnp.int32)
+
+    # the pool itself placed sharded on its page axis: the prefill chunk
+    # and the decode step write it in place, so it stays sharded, no
+    # collective gathers it, and the step stays bit-exact
+    from jax.sharding import NamedSharding, PartitionSpec
+    on_pages = NamedSharding(mesh, PartitionSpec(None, "s"))
+    jchunk = jax.jit(lambda p, c, t: dec.paged_prefill_chunk(
+        p, c, t, cfg, None, slot=1, count=3))
+    rep = jchunk(params, rep, jnp.arange(4, dtype=jnp.int32))
+    shd = dict(shd, blocks=jax.device_put(shd["blocks"], on_pages))
+    for prog, args in ((jchunk, (jnp.arange(4, dtype=jnp.int32),)),
+                       (jshd, (tok,))):
+        hlo = prog.lower(params, shd, *args).compile().as_text()
+        for line in hlo.splitlines():
+            m = re.search(r"= \w+\[([\d,]+)\]\S* all-gather\(", line)
+            if m and np.prod([int(d) for d in m.group(1).split(",")]) \
+                    >= rep["blocks"]["pos0"].size:
+                raise AssertionError(f"pool-sized all-gather:\n{line}")
+    shd = jchunk(params, shd, jnp.arange(4, dtype=jnp.int32))
+    lr, rep = jrep(params, rep, tok)
+    ls, shd = jshd(params, shd, tok)
+    np.testing.assert_array_equal(np.asarray(lr), np.asarray(ls))
+    for k, leaf in shd["blocks"].items():
+        assert leaf.sharding.is_equivalent_to(on_pages, leaf.ndim), k
+        np.testing.assert_array_equal(np.asarray(leaf),
+                                      np.asarray(rep["blocks"][k]))
     print("CHECK_OK")
 
 

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from repro import vx
+from repro.configs import get_arch
 from repro.core import accessfuse, scg
 from repro.models import decode as dec
 from repro.models.transformer import ModelConfig, init_params
@@ -85,6 +86,52 @@ def test_paged_scatter_appends_and_drops():
     out2 = np.asarray(vx.scatter(spec, pool, vals, table=table,
                                  pos=jnp.asarray([6, -1, 99], np.int32)))
     np.testing.assert_array_equal(out2, np.zeros_like(out2))
+
+
+@pytest.mark.parametrize("quantize", (None, "int8"))
+def test_paged_stack_access_matches_each_layer_alone(quantize):
+    """A pool stacked over layers, addressed in place: one scatter of
+    per-layer beats (``(L, *batch, *trail)`` values) writes what a scatter
+    into each layer's own pool writes, and ``lead=l`` writes or reads
+    layer ``l`` of the stack exactly as on that layer alone — float and
+    quantized (scales widened per layer) alike, drops included."""
+    rng = np.random.default_rng(3)
+    L, ps, pages, P, K, D = 3, 4, 2, 5, 2, 3
+    spec = vx.Paged(page_size=ps, pages=pages, trail=2)
+    dtype = jnp.int8 if quantize else jnp.float32
+    pool = jnp.asarray(rng.integers(-9, 9, (L, P, ps, K, D)), dtype)
+    scales = (jnp.asarray(rng.uniform(0.1, 1.0, (L, P, K)), jnp.float32)
+              if quantize else None)
+    table = jnp.asarray([[1, -1], [3, 0], [-1, -1]], np.int32)
+    pos = jnp.asarray([2, 5, 1], np.int32)
+    vals = jnp.asarray(rng.normal(size=(L, 3, K, D)), jnp.float32)
+
+    def pair(out):                  # (pool,) or (pool, scales)
+        return out if quantize else (out,)
+
+    def alone(layer, v):            # the layer's own pool, scattered
+        return pair(vx.scatter(
+            spec, pool[layer], v, table=table, pos=pos,
+            scales=None if scales is None else scales[layer]))
+
+    def check(got, want):
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+    every = pair(vx.scatter(spec, pool, vals, table=table, pos=pos,
+                            scales=scales))
+    check(every, [jnp.stack(x) for x in
+                  zip(*(alone(layer, vals[layer]) for layer in range(L)))])
+    one = pair(vx.scatter(spec, pool, vals[1], table=table, pos=pos,
+                          scales=scales, lead=jnp.asarray(1)))
+    new1 = alone(1, vals[1])
+    check(one, [x.at[1].set(n) for x, n in
+                zip((pool,) if scales is None else (pool, scales), new1)])
+    read = vx.gather(spec, one[0], table=table[:2], lead=jnp.asarray(1),
+                     scales=None if scales is None else one[1])
+    np.testing.assert_array_equal(np.asarray(read), np.asarray(vx.gather(
+        spec, one[0][1], table=table[:2],
+        scales=None if scales is None else one[1][1])))
 
 
 def test_append_paged_token_interleaves_beat():
@@ -347,8 +394,10 @@ def test_paged_fused_gather_is_one_program_per_step():
         lambda p, c, t: dec.paged_decode_step(p, c, t, cfg_ref, None,
                                               fuse=False),
         params, cache, tok)
-    # 2 leaves x 2 superblocks of page gathers collapse into ONE
-    assert gp - gf == 2 * 2 - 1, (gf, gp)
+    # 2 leaves x 2 superblocks of page gathers collapse into ONE, and the
+    # in-place append looks its page ids up once per leaf (one take per
+    # scatter) instead of once per leaf per superblock
+    assert gp - gf == (2 * 2 - 1) + (2 * 2 - 2), (gf, gp)
 
     cfg = _cfg(layers=4, hd=64, scan=False, impl="pallas", positions=2,
                mlp="none", d_ff=0)
@@ -379,3 +428,114 @@ def test_paged_plan_cache_steady_state_under_jit():
     for _ in range(4):
         _, cache = jp(params, cache, tok)
     assert vx.PLANS.stats()["misses"] == warm
+
+
+# ---------------------------------------------------------------------------
+# In-place pool writes: bit-identical to the scanned oracle route
+# ---------------------------------------------------------------------------
+
+_ROUTE_CASES = {
+    # id: (arch smoke config, pool dtype, quantize, scenario, scan_layers)
+    "qwen3-float32": ("qwen3-0.6b", jnp.float32, None, "fresh", True),
+    "qwen3-float32-unrolled": ("qwen3-0.6b", jnp.float32, None, "fresh",
+                               False),
+    "qwen3-bfloat16": ("qwen3-0.6b", jnp.bfloat16, None, "fresh", True),
+    "qwen3-int8": ("qwen3-0.6b", jnp.float32, "int8", "fresh", True),
+    "qwen3-int8-unrolled": ("qwen3-0.6b", jnp.float32, "int8", "fresh",
+                            False),
+    "qwen3-fp8": ("qwen3-0.6b", jnp.float32, "fp8", "fresh", True),
+    "gemma3-windows-float32": ("gemma3-12b", jnp.float32, None, "fresh",
+                               True),
+    "gemma3-windows-int8": ("gemma3-12b", jnp.bfloat16, "int8", "fresh",
+                            True),
+    "jamba-hybrid-float32": ("jamba-1.5-large-398b", jnp.float32, None,
+                             "fresh", True),
+    "jamba-hybrid-int8": ("jamba-1.5-large-398b", jnp.float32, "int8",
+                          "fresh", True),
+    "qwen3-shared-prefix-float32": ("qwen3-0.6b", jnp.float32, None,
+                                    "shared_prefix", True),
+    "qwen3-shared-prefix-int8": ("qwen3-0.6b", jnp.float32, "int8",
+                                 "shared_prefix", True),
+    "qwen3-exhausted-float32": ("qwen3-0.6b", jnp.float32, None,
+                                "exhausted", True),
+    "qwen3-exhausted-int8": ("qwen3-0.6b", jnp.float32, "int8",
+                             "exhausted", True),
+}
+
+
+def _serve_sequence(cfg, params, fuse, dtype, quantize, scenario):
+    """Two slots through prefill chunks, decode steps and a verify step,
+    every program on one route (``fuse``): returns what each program
+    returned besides the cache, the final cache, and the pool writes the
+    programs' traces counted.  ``shared_prefix``: slot 1 adopts slot 0's
+    first page before its own chunk; ``exhausted``: a four-page pool, so
+    the first decode step's page-boundary appends find the free stack
+    empty and drop."""
+    ps, max_len = 4, 32
+    cache = dec.init_paged_cache(
+        cfg, 2, max_len, ps, dtype, quantize=quantize,
+        num_pages=4 if scenario == "exhausted" else None)
+    chunk = jax.jit(lambda p, c, t, s, n: dec.paged_prefill_chunk(
+        p, c, t, cfg, None, slot=s, count=n, fuse=fuse))
+    step = jax.jit(lambda p, c, t: dec.paged_decode_step(
+        p, c, t, cfg, None, fuse=fuse))
+    verify = jax.jit(lambda p, c, t, n: dec.paged_verify_step(
+        p, c, t, cfg, None, n_draft=n, fuse=fuse))
+    dec.POOL_WRITES.clear()
+    prompt = jnp.arange(1, 9, dtype=jnp.int32) * 5 % cfg.vocab
+    cache = chunk(params, cache, prompt[:4], 0, 4)
+    cache = chunk(params, cache, prompt[4:], 0, 3)
+    if scenario == "shared_prefix":
+        run = jnp.full((max_len // ps,), -1, jnp.int32).at[0].set(
+            cache["table"][0, 0])
+        cache = dec.paged_adopt_prefix(cfg, cache, 1, run)
+        cache = chunk(params, cache, prompt[4:] + 1, 1, 3)
+    else:
+        cache = chunk(params, cache, prompt[:4] + 2, 1, 4)
+        cache = chunk(params, cache, prompt[4:] + 2, 1, 2)
+    outs, tok = [], jnp.asarray([7, 11], jnp.int32)
+    for _ in range(3):
+        logits, cache = step(params, cache, tok)
+        outs.append(logits)
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+    drafts = jnp.stack([tok, (tok + 1) % cfg.vocab, (tok + 2) % cfg.vocab],
+                       axis=1)
+    *verified, cache = verify(params, cache, drafts,
+                              jnp.asarray([3, 2], jnp.int32))
+    logits, cache = step(params, cache, verified[1][:, 0])
+    return outs + verified + [logits], cache, dec.POOL_WRITES.stats()
+
+
+@pytest.mark.parametrize("case", list(_ROUTE_CASES))
+def test_in_place_pool_writes_match_the_scanned_route(case):
+    """The served route writes each program's new beats into the pool
+    stack in place (decode and verify: one scatter per pool leaf after the
+    layer loop; prefill chunk: the stack rides the scan's carry).  After
+    prefill chunks, decode steps and a verify step every leaf of the cache
+    — pools, scales, table, free stack, refcounts, positions, recurrent
+    state — is bit-identical to the ``fuse=False`` route that scans the
+    stack one layer's pool at a time, and so are the logits, tokens and
+    commits; each route's traces count only their own pool writes."""
+    arch, dtype, quantize, scenario, scan = _ROUTE_CASES[case]
+    cfg = dataclasses.replace(get_arch(arch).smoke, scan_layers=scan)
+    params = init_params(cfg, jax.random.key(0))
+    outs, caches, writes = zip(*(
+        _serve_sequence(cfg, params, fuse, dtype, quantize, scenario)
+        for fuse in (True, False)))
+    leaves = 3 * len(dec._pool_keys(caches[0], [
+        i for i, k in enumerate(cfg.block_pattern) if k == "attn"]))
+    assert writes == ({"in_place": leaves}, {"scanned": leaves})
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a), np.asarray(b)), caches[0], caches[1])
+    n = None
+    if scenario == "exhausted":
+        assert int(caches[0]["free_top"]) == 0
+        assert int((caches[0]["table"] >= 0).sum()) == 4
+        if quantize is None:
+            # from the second step on the appends drop: the per-access
+            # float oracle reads its pool after the append, so it attends
+            # to zeros where the fused step attends to the fresh beat —
+            # their logits differ by design (their caches do not)
+            n = 1
+    for a, b in list(zip(*outs))[:n]:
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

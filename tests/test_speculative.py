@@ -327,7 +327,10 @@ def test_verify_fuses_page_gathers_ref():
         p, c, t, cfg, None, n_draft=n, fuse=True), params, cache, toks, nd)
     gp = _count_gathers(lambda p, c, t, n: dec.paged_verify_step(
         p, c, t, cfg, None, n_draft=n, fuse=False), params, cache, toks, nd)
-    assert gp - gf == 3, (gf, gp)
+    # three page gathers fewer, and the in-place append looks its page ids
+    # up once per leaf (one take per scatter), not once per leaf per
+    # superblock
+    assert gp - gf == 3 + 2, (gf, gp)
 
 
 def test_verify_single_pinned_launch_pallas():
@@ -396,6 +399,7 @@ def _plain_streams(cfg, params, max_new=12, **kw):
 
 def test_scheduler_stream_equality_uniform_k():
     cfg, params, dcfg, dparams = _sched_pair()
+    dec.POOL_WRITES.clear()
     oracle = _plain_streams(cfg, params)
     ss = Scheduler(cfg, params, slots=3, max_len=64, page_size=4,
                    speculate=4, draft_cfg=dcfg, draft_params=dparams,
@@ -403,6 +407,9 @@ def test_scheduler_stream_equality_uniform_k():
     rs = [ss.submit(p, max_new_tokens=12) for p in _PROMPTS]
     _drain(ss, rs)
     assert [list(r.tokens) for r in rs] == oracle
+    # the served programs (target chunk, step and verify; the draft's
+    # chunk and step) write every pool leaf in place
+    assert set(dec.POOL_WRITES.stats()) == {"in_place"}
     st = ss.stats()
     assert st["speculative"]["proposed"] > 0
     assert st["speculative"]["accepted"] > 0

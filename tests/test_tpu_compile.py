@@ -7,7 +7,9 @@ topology is described inside a fixture, never at import: only one process
 may hold the TPU library, and every test worker imports this file.
 """
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -147,6 +149,110 @@ def _serve_programs(cfg, one_chip):
             jax.ShapeDtypeStruct((PAGE_SIZE,), jnp.int32, sharding=one_chip),
             i32, i32))
     return step, chunk
+
+
+def _pool_moves(text: str, shapes) -> list[str]:
+    """Instructions of compiled ``text`` that copy, slice or update a
+    whole array of one of ``shapes`` (``f32[...]`` strings): a result of
+    that shape whose opcode or name says copy, dynamic-slice or
+    dynamic-update-slice (device traces name fusions after the latter)."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = ([a-z0-9]+\[[\d,]*\])\S* "
+                     r"([\w-]+)\(", line)
+        if not m or m.group(2) not in shapes:
+            continue
+        name = m.group(1).replace("_", "-")
+        if any(op == m.group(3) or op in name
+               for op in ("copy", "dynamic-slice", "dynamic-update-slice")):
+            found.append(line.strip()[:160])
+    return found
+
+
+def _aliased_params(text: str) -> set[int]:
+    """Entry parameters the compiled module aliases to an output."""
+    head = text.split("\n", 1)[0]
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", head)
+    return ({int(p) for p in re.findall(r"\((\d+), \{", alias.group(1))}
+            if alias else set())
+
+
+def _param_index(text: str, shape: str) -> int:
+    """Index of the first entry parameter of ``shape``."""
+    layout = re.search(r"entry_computation_layout=\{\((.*?)\)->",
+                       text.split("\n", 1)[0]).group(1)
+    return re.findall(r"[a-z0-9]+\[[\d,]*\]", layout).index(shape)
+
+
+def _expert_cell_attention():
+    """The expert cell's attention and pool geometry (d 2048, 32/4 heads of
+    128, a float32 pool of 4 KV heads, bfloat16 weights) over 8 of its
+    layers, with a narrow dense FFN: at this pool layout a write whose
+    window spans the layer axis makes XLA lay the whole pool out twice
+    around it."""
+    return dataclasses.replace(get_arch("qwen3-0.6b").model, d_model=2048,
+                               n_layers=8, n_heads=32, n_kv_heads=4,
+                               d_ff=64, param_dtype="bfloat16")
+
+
+@pytest.mark.parametrize("make_cfg,slots", [
+    (lambda: get_arch("qwen3-0.6b").model, SLOTS),
+    (_expert_cell_attention, 16),
+], ids=["qwen3-0.6b", "expert-cell-attention"])
+def test_serve_programs_write_the_pool_in_place(for_tpu, one_chip,
+                                                 monkeypatch, make_cfg,
+                                                 slots):
+    """At qwen3-0.6b's published widths and chip_smoke.py's serve geometry
+    (8 slots, ``max_len`` 2048, 16-token pages, a 3.76 GB float32 pool),
+    and at the expert cell's pool geometry (16 slots), the scheduler's
+    decode step and prefill chunk compile for the chip with no copy,
+    dynamic-slice or dynamic-update-slice of the pool stack, of one
+    layer's pool or of the stack flattened to one page axis; the donated
+    pool parameter aliases an output; and beside what each call holds
+    anyway, XLA's temporaries are smaller than one layer's pool.  What
+    each call holds anyway: the bfloat16 copy of float32 layer weights
+    (``cast_params``; none for bfloat16 weights) in the chunk, and the
+    whole-row page gather with its K/V split in the step."""
+    cfg = make_cfg()
+    ns = cfg.n_superblocks
+    # the scheduler's cache as shapes: nothing pool-sized is allocated
+    init = dec.init_paged_cache
+    monkeypatch.setattr(dec, "init_paged_cache",
+                        lambda *a, **k: jax.eval_shape(
+                            lambda: init(*a, **k)))
+    abstract = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), abstract)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    with vx.use("pallas"):
+        sched = Scheduler(cfg, params, slots=slots, max_len=MAX_LEN,
+                          page_size=PAGE_SIZE)
+        state = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), sched.cache.state)
+        step = sched._step.lower(
+            params, state,
+            jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((slots,), bool, sharding=one_chip)
+        ).compile()
+        chunk = sched._chunk.lower(
+            params, state,
+            jax.ShapeDtypeStruct((PAGE_SIZE,), jnp.int32, sharding=one_chip),
+            i32, i32).compile()
+    pool = state["blocks"]["pos0"].shape
+    layer_bytes = math.prod(pool[1:]) * 4    # 134 MB for qwen3-0.6b
+    shapes = {"f32[" + ",".join(map(str, s)) + "]"
+              for s in (pool, pool[1:], (pool[0] * pool[1],) + pool[2:])}
+    stack = "f32[" + ",".join(map(str, pool)) + "]"
+    weights = sum(a.size * cfg.cdtype.itemsize
+                  for a in jax.tree.leaves(abstract["blocks"])
+                  if a.dtype != cfg.cdtype)
+    rows = ns * slots * MAX_LEN * math.prod(pool[3:]) * 4
+    for compiled, held in ((chunk, weights), (step, 2 * rows)):
+        text = compiled.as_text()
+        assert _pool_moves(text, shapes) == []
+        assert _param_index(text, stack) in _aliased_params(text)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp - held < layer_bytes, (temp, held)
 
 
 def _assert_one_named_split(step: str, chunk: str) -> None:

@@ -292,73 +292,51 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      cache_len, *, window: int | None = None) -> jax.Array:
     """Single-token decode. q: (B, H, D); caches: (B, S, K, D).
 
-    Masks positions >= cache_len (and outside the sliding window). This is
-    the per-shard body of the sequence-parallel long-context path — callers
-    may psum-merge the returned (out, lse) across a mesh axis.
+    Masks positions >= cache_len (a scalar, or (B, 1) per row) and those
+    outside the sliding window: the one-query call of
+    :func:`chunk_attention`, its query at position ``cache_len - 1``.
     """
-    B, H, D = q.shape
+    q_pos = jnp.atleast_1d(jnp.asarray(cache_len) - 1)
+    return chunk_attention(q[:, None], k_cache, v_cache, q_pos,
+                           window=window)[:, 0]
+
+
+def chunk_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                    q_pos: jax.Array, *,
+                    window: int | None = None) -> jax.Array:
+    """C-query attention over a cache.  q: (B, C, H, D); caches:
+    (B, S, K, D); ``q_pos``: (C,) absolute positions of the queries, or
+    (B, C) when each batch row sits at its own offset (the paged step).
+
+    Query ``i`` attends to cache positions ``j <= q_pos[i]`` and, for
+    sliding-window layers, only within ``q_pos[i] - j < window``.  The C
+    queries fold into the GQA group axis, ``(B, C, H, D)`` to
+    ``(B, K, G*C, D)``, so the score and value contractions are one
+    product per (batch, kv head) at any C, and at C = 1 exactly the
+    single-token decode's.  The group index is the major one, so the
+    (C, S) mask broadcasts over whole row blocks.  fp32 scores, NEG_INF
+    mask, max/exp/sum softmax."""
+    B, C, H, D = q.shape
     S, K = k_cache.shape[1], k_cache.shape[2]
     G = H // K
-    qt = q.reshape(B, K, G, D)
+    qt = jnp.moveaxis(q.reshape(B, C, K, G, D), 1, 3).reshape(B, K, G * C, D)
     s = jnp.einsum("bkgd,bskd->bkgs", qt, k_cache.astype(q.dtype),
                    preferred_element_type=jnp.float32) * D ** -0.5
-    pos = jnp.arange(S)
-    mask = pos[None, :] < cache_len  # (B?, S) cache_len scalar or (B,1)
+    j = jnp.arange(S)
+    qp = q_pos[..., None]                     # (C, 1) or (B, C, 1)
+    mask = j <= qp
     if window is not None:
-        mask &= pos[None, :] >= (cache_len - window)
-    s = jnp.where(mask[:, None, None, :] if mask.ndim == 2
-                  else mask[None, None, None, :], s, NEG_INF)
+        mask &= (qp - j) < window
+    s = jnp.where(mask[..., None, None, :, :], s.reshape(B, K, G, C, S),
+                  NEG_INF).reshape(B, K, G * C, S)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
     out = jnp.einsum("bkgs,bskd->bkgd", p / jnp.maximum(l, 1e-30),
                      v_cache.astype(q.dtype),
                      preferred_element_type=jnp.float32)
-    return out.reshape(B, H, D).astype(q.dtype)
-
-
-def chunk_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                    q_pos: jax.Array, *,
-                    window: int | None = None) -> jax.Array:
-    """C-query prefill-chunk attention.  q: (B, C, H, D); caches:
-    (B, S, K, D); ``q_pos``: (C,) absolute positions of the queries,
-    or (B, C) when each batch row sits at its own offset (the
-    speculative K-token verify step; negative entries mark pad queries
-    that attend to nothing real — their outputs are garbage and must
-    be gated by the caller).
-
-    Query ``i`` attends to cache positions ``j <= q_pos[i]`` (causal
-    over the already-written cache, which includes the chunk's own
-    beats) and, for sliding-window layers, only within
-    ``q_pos[i] - j < window`` — the same attended set
-    :func:`decode_attention` masks one query at a time.  Numerics
-    mirror decode_attention (fp32 scores, NEG_INF mask, max/exp/sum
-    softmax), so a chunked prefill agrees with forced token-by-token
-    decode to float tolerance."""
-    B, C, H, D = q.shape
-    S, K = k_cache.shape[1], k_cache.shape[2]
-    G = H // K
-    qt = q.reshape(B, C, K, G, D)
-    s = jnp.einsum("bckgd,bskd->bkgcs", qt, k_cache.astype(q.dtype),
-                   preferred_element_type=jnp.float32) * D ** -0.5
-    j = jnp.arange(S)
-    if q_pos.ndim == 2:                              # (B, C) per-row offsets
-        mask = j[None, None, :] <= q_pos[:, :, None]  # (B, C, S)
-        if window is not None:
-            mask &= (q_pos[:, :, None] - j[None, None, :]) < window
-        s = jnp.where(mask[:, None, None], s, NEG_INF)
-    else:
-        mask = j[None, :] <= q_pos[:, None]          # (C, S)
-        if window is not None:
-            mask &= (q_pos[:, None] - j[None, :]) < window
-        s = jnp.where(mask[None, None, None], s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bkgcs,bskd->bkgcd", p / jnp.maximum(l, 1e-30),
-                     v_cache.astype(q.dtype),
-                     preferred_element_type=jnp.float32)
-    return jnp.moveaxis(out, 3, 1).reshape(B, C, H, D).astype(q.dtype)
+    out = jnp.moveaxis(out.reshape(B, K, G, C, D), 3, 1)
+    return out.reshape(B, C, H, D).astype(q.dtype)
 
 
 def cross_attention(params, x: jax.Array, enc_k: jax.Array, enc_v: jax.Array,

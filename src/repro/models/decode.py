@@ -398,92 +398,6 @@ def paged_release_slot(cfg: ModelConfig, cache: dict, slot) -> dict:
                 ref=ref, blocks=blocks)
 
 
-def paged_insert_prefill(cfg: ModelConfig, cache: dict, slot,
-                         cache_states, length, state_len: int) -> dict:
-    """Embed B=1 prefill states into a slot's pages.
-
-    ``state_len`` (static) is the sequence extent the prefill ran on —
-    any length; attention beats are zero-padded here to whole pages
-    (the padded tail is masked by ``eff_len`` until the decode loop
-    overwrites it in place).  ``length`` is the true prompt length and
-    may be TRACED, so ONE jit entry serves every prompt of the same
-    ``state_len``.  NOTE on padding the PREFILL itself (running it on
-    more tokens than the prompt): that is only sound for windowless
-    attention-only stacks — a ring-trimmed window leaf is cut at the
-    padded length (real in-window beats are lost) and recurrent state
-    absorbs the pad tokens irreversibly; the serving scheduler therefore
-    pads the prefill only when every block is windowless attention.
-    Allocates ``ceil(state_len / page_size)`` pages off the free stack.
-    jit-safe (``slot``/``length`` may be traced)."""
-    attn_pos, ps, n_seq = _paged_geometry(cfg, cache)
-    n_pg = -(-state_len // ps)
-    sp = n_pg * ps
-    if n_pg > n_seq:
-        raise ValueError(f"state_len={state_len} needs {n_pg} pages, the "
-                         f"table holds {n_seq}")
-    slot = jnp.asarray(slot, jnp.int32)
-    length = jnp.asarray(length, jnp.int32)
-    free, free_top = cache["free"], cache["free_top"]
-    # exhaustion degrades locally (like the decode-step allocator): pages
-    # beyond the free count stay -1 in the table and their beats are
-    # dropped — never an aliased page.  serve/paged_cache.py refuses the
-    # insert host-side before it comes to that.
-    k = jnp.arange(n_pg)
-    have = k < free_top
-    newp = jnp.where(have, free[jnp.clip(free_top - 1 - k, 0,
-                                         free.shape[0] - 1)], -1)
-    free_top = free_top - jnp.sum(have.astype(jnp.int32))
-    table = cache["table"].at[slot, :n_pg].set(newp)
-    pos = cache["pos"].at[slot].set(length)
-    scatter_ids = jnp.where(have, newp, free.shape[0])
-    ref = cache["ref"].at[scatter_ids].add(1, mode="drop")
-    quantized = _pool_quantized(cache, attn_pos)
-    blocks = dict(cache["blocks"])
-    for i, kind in enumerate(cfg.block_pattern):
-        st = cache_states[f"pos{i}"]
-        leaf = blocks[f"pos{i}"]
-        if kind == "attn":
-            # quantized pools keep the prefill states float here and
-            # quantize per page below (casting to the int leaf dtype
-            # would truncate)
-            kv = st.astype(jnp.float32 if quantized else leaf.dtype)
-            w = cfg.window_pattern[i]
-            if w is not None and kv.shape[2] < state_len:
-                # prefill ring-trimmed the window at state_len: un-roll
-                # to natural order and park at positions
-                # [state_len - W, state_len)
-                W = kv.shape[2]
-                nat = jnp.roll(kv, -(state_len % W), axis=2)
-                full = jnp.zeros(kv.shape[:2] + (sp,) + kv.shape[3:],
-                                 kv.dtype)
-                kv = full.at[:, :, state_len - W:state_len].set(nat)
-            elif kv.shape[2] != state_len:
-                raise ValueError(
-                    f"prefill states carry {kv.shape[2]} beats; expected "
-                    f"state_len={state_len}")
-            if kv.shape[2] < sp:       # zero-pad to whole pages
-                kv = jnp.pad(kv, ((0, 0), (0, 0), (0, sp - kv.shape[2]),
-                                  (0, 0), (0, 0)))
-            beats = kv[:, 0].reshape(kv.shape[0], n_pg, ps, *kv.shape[3:])
-            if quantized:
-                # per-(superblock, page, head) max-abs scale over the
-                # in-page and feature axes, then quantize the beats
-                from repro.core import quant
-                s = quant.scale_for(beats, leaf.dtype, axis=(2, 4))
-                beats = quant.quantize(beats, s[:, :, None, :, None],
-                                       leaf.dtype)
-                blocks[f"scl{i}"] = blocks[f"scl{i}"].at[
-                    :, scatter_ids].set(s, mode="drop")
-            blocks[f"pos{i}"] = leaf.at[:, scatter_ids].set(beats,
-                                                            mode="drop")
-        else:
-            blocks[f"pos{i}"] = jax.tree.map(
-                lambda c, s: c.at[:, slot].set(s[:, 0].astype(c.dtype)),
-                leaf, st)
-    return dict(cache, pos=pos, table=table, free=free, free_top=free_top,
-                ref=ref, blocks=blocks)
-
-
 def paged_adopt_prefix(cfg: ModelConfig, cache: dict, slot,
                        page_ids) -> dict:
     """Point a freshly-admitted slot's page table at SHARED prefix pages.
@@ -677,9 +591,77 @@ def _scan_layers(cfg: ModelConfig, sb_step, carry, xs):
     return carry, jax.tree.map(lambda *ys: jnp.stack(ys), *outs)
 
 
+def _recurrent_step(kind: str, p, cfg: ModelConfig):
+    """The one-token ``(h_t, state) -> (y, state)`` step of the recurrent
+    block kind ``kind`` (mamba / mlstm / slstm) with position params
+    ``p``."""
+    if kind == "mamba":
+        pm = dict(p["mamba"])
+        pm["in_proj"] = pm["in_proj"].reshape(cfg.d_model, 2 * cfg.mamba.ed)
+        return lambda h, st: mamba_decode_step(pm, h, st, cfg.mamba)
+    if kind == "mlstm":
+        px = dict(p["xl"])
+        px["up"] = px["up"].reshape(cfg.d_model, 2 * cfg.xlstm.m_inner)
+        return lambda h, st: mlstm_decode_step(px, h, st, cfg.xlstm)
+    return lambda h, st: slstm_decode_step(p["slstm"], h, st, cfg.xlstm)
+
+
+def _gated_scan(step_fn, state0, h, valid):
+    """Advance per-row recurrent state over the tokens of ``h`` (R, T, d):
+    row ``r`` takes token ``t`` only where ``valid[r, t]``; elsewhere its
+    state holds and its output is zero.  Returns (final state, the state
+    after each token stacked on a leading T axis, outputs (R, T, d))."""
+    def tok(st, inp):
+        ht, on = inp                                 # (R, d), (R,)
+        y, st2 = step_fn(ht, st)
+        st2 = _keep_active(st2, st, on)
+        return st2, (st2, jnp.where(on[:, None], y, 0.0))
+    st, (sts, ys) = jax.lax.scan(
+        tok, state0, (jnp.swapaxes(h, 0, 1), jnp.swapaxes(valid, 0, 1)))
+    return st, sts, jnp.swapaxes(ys, 0, 1).astype(h.dtype)
+
+
+def _ffn(cfg: ModelConfig, ctx, i: int, p, x, valid, policy):
+    """Position ``i``'s FFN over ``x`` (..., d), residual included: the
+    expert FFN, routing only the ``valid`` tokens, or the dense one.  The
+    dense FFN takes (rows, tokens, d); a flat (N, d) stream runs as N
+    one-token rows, the dense decode step's shape.  Returns (x, the
+    expert layer's units, or None)."""
+    if cfg.moe_pattern[i]:
+        return _moe_ffn(p, x, cfg, valid)
+    y, _ = _ffn_apply(p, x if x.ndim == 3 else x[:, None], cfg, ctx, i,
+                      policy=policy)
+    return y.reshape(x.shape), None
+
+
+def _superblock(cfg: ModelConfig, ctx, sb_p, x, *, attend, recur, valid,
+                ffn_pol):
+    """One superblock of a paged program over ``x`` (..., d): each
+    position's mixer, then its FFN.  ``attend(i, attn_params, h)``
+    returns position ``i``'s attention output (..., H, D); ``recur(i,
+    step, h)`` runs a recurrent kind's one-token ``step`` over the
+    tokens and returns its output (..., d).  Each writes its own cache
+    leaves.  Returns (x, the expert layers' units by position)."""
+    units = {}
+    for i, kind in enumerate(cfg.block_pattern):
+        p = sb_p[f"pos{i}"]
+        h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
+        if kind == "attn":
+            out = attend(i, p["attn"], h)
+            x = x + (out.reshape(x.shape[:-1] + (-1,))
+                     @ p["attn"]["wo"]).astype(x.dtype)
+        else:
+            x = x + recur(i, _recurrent_step(kind, p, cfg), h)
+        if cfg.pos_has_ffn(i):
+            x, u = _ffn(cfg, ctx, i, p, x, valid, ffn_pol)
+            if u is not None:
+                units[f"pos{i}"] = u
+    return x, units
+
+
 def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
                         cfg: ModelConfig, ctx, *, slot, count,
-                        fuse: bool | None = None) -> dict:
+                        fuse: bool = True) -> dict:
     """Prefill up to ``tokens.shape[0]`` prompt tokens into ONE slot,
     starting at the slot's current position (mid-page starts — e.g.
     after a copy-on-write tail fork — are fine).
@@ -711,15 +693,13 @@ def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
     into the carried stack at its own superblock (``lead=``) and gathers
     the slot's row from the same stack; nothing pool-sized is sliced or
     written back, and XLA aliases the carry to the donated input.
-    ``fuse=False`` (default ``cfg.step_fusion``) is the test oracle: the
-    stack rides the scan as an operand and a result, one layer's pool at
-    a time."""
+    ``fuse=False`` is the test oracle: the stack rides the scan as an
+    operand and a result, one layer's pool at a time."""
     from repro.models.transformer import cast_params
     params = cast_params(params, cfg)
     if cfg.encoder is not None:
         raise NotImplementedError("paged serving covers decoder-only "
                                   "models; use encdec.decode_step")
-    fuse = cfg.step_fusion if fuse is None else fuse
     pol = cfg.vx_policy
     C = tokens.shape[0]
     slot = jnp.asarray(slot, jnp.int32)
@@ -769,18 +749,7 @@ def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
     pools = {k: v for k, v in blocks_in.items() if k not in scanned}
 
     x = layers.embed(tokens, params["embed"]).astype(cfg.cdtype)[None]
-
-    def _tok_scan(step_fn, state0, h, keep_dtype):
-        """Advance per-slot recurrent state over the chunk's tokens; pad
-        tokens are gated out of both the carry and the output."""
-        def tok(st, inp):
-            ht, on = inp                         # ht (1, d), on scalar
-            y, st2 = step_fn(ht, st)
-            st2 = _keep_active(st2, st, on[None])
-            return st2, jnp.where(on, y, 0.0)
-        st, ys = jax.lax.scan(tok, state0,
-                              (jnp.swapaxes(h, 0, 1), real))
-        return st, jnp.swapaxes(ys, 0, 1).astype(keep_dtype)
+    valid = real[None]
 
     def _slot_state(sb_state):
         return jax.tree.map(
@@ -797,72 +766,42 @@ def paged_prefill_chunk(params, cache: dict, tokens: jax.Array,
         x, pools = carry
         sb_p, sb_c, layer = inp
         pools = dict(pools)
-        new_c, units = {}, {}
-        for i, kind in enumerate(cfg.block_pattern):
-            p = sb_p[f"pos{i}"]
-            if kind == "attn":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                q, k, v, kv = attention.qkv_project(
-                    p["attn"], h, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
-                    tpos[None], cfg.rope_theta, policy=pol)
-                # in place: this superblock of the carried stack;
-                # oracle: the layer's own pool (P, ps, K, 2D)
-                src, dst, lead = ((pools, pools, layer) if fuse
-                                  else (sb_c, new_c, None))
-                # quantize-on-write (scale widens monotonically), then
-                # the attention read dequantizes the slot's whole prefix
-                # — including the beats just written — in the same
-                # one-program gather
-                pool = vx.scatter(spec, src[f"pos{i}"], kv[0], table=table_c,
-                                  pos=wpos, scales=src.get(f"scl{i}"),
-                                  lead=lead, policy=pol)
-                if quantized:
-                    pool, dst[f"scl{i}"] = pool
-                full = vx.gather(spec, pool, table=row[None],
-                                 scales=dst.get(f"scl{i}"), lead=lead,
-                                 policy=pol)
-                dst[f"pos{i}"] = pool
-                k_all, v_all = vx.transpose(
-                    vx.Segment(n=full.shape[-1], fields=2), full,
-                    policy=pol)
-                out = attention.chunk_attention(
-                    q, k_all, v_all, tpos, window=cfg.window_pattern[i])
-                x = x + (out.reshape(1, C, cfg.n_heads * cfg.hd)
-                         @ p["attn"]["wo"]).astype(x.dtype)
-            elif kind == "mamba":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                pm = dict(p["mamba"])
-                pm["in_proj"] = pm["in_proj"].reshape(cfg.d_model,
-                                                      2 * cfg.mamba.ed)
-                st, y = _tok_scan(
-                    lambda ht, st: mamba_decode_step(pm, ht, st,
-                                                     cfg.mamba),
-                    _slot_state(sb_c[f"pos{i}"]), h, x.dtype)
-                x = x + y
-                new_c[f"pos{i}"] = _put_slot(sb_c[f"pos{i}"], st)
-            elif kind == "mlstm":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                px = dict(p["xl"])
-                px["up"] = px["up"].reshape(cfg.d_model,
-                                            2 * cfg.xlstm.m_inner)
-                st, y = _tok_scan(
-                    lambda ht, st: mlstm_decode_step(px, ht, st,
-                                                     cfg.xlstm),
-                    _slot_state(sb_c[f"pos{i}"]), h, x.dtype)
-                x = x + y
-                new_c[f"pos{i}"] = _put_slot(sb_c[f"pos{i}"], st)
-            elif kind == "slstm":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                st, y = _tok_scan(
-                    lambda ht, st: slstm_decode_step(p["slstm"], ht, st,
-                                                     cfg.xlstm),
-                    _slot_state(sb_c[f"pos{i}"]), h, x.dtype)
-                x = x + y
-                new_c[f"pos{i}"] = _put_slot(sb_c[f"pos{i}"], st)
-            if cfg.pos_has_ffn(i) and cfg.moe_pattern[i]:
-                x, units[f"pos{i}"] = _moe_ffn(p, x, cfg, real[None])
-            elif cfg.pos_has_ffn(i):
-                x, _ = _ffn_apply(p, x, cfg, ctx, i, policy=pol)
+        new_c = {}
+        # in place: this superblock of the carried stack; oracle: the
+        # layer's own pool (P, ps, K, 2D)
+        src, dst, lead = ((pools, pools, layer) if fuse
+                          else (sb_c, new_c, None))
+
+        def attend(i, pa, h):
+            q, k, v, kv = attention.qkv_project(
+                pa, h, cfg.n_heads, cfg.n_kv_heads, cfg.hd, tpos[None],
+                cfg.rope_theta, policy=pol)
+            # quantize-on-write (scale widens monotonically), then the
+            # attention read dequantizes the slot's whole prefix —
+            # including the beats just written — in the same one-program
+            # gather
+            pool = vx.scatter(spec, src[f"pos{i}"], kv[0], table=table_c,
+                              pos=wpos, scales=src.get(f"scl{i}"),
+                              lead=lead, policy=pol)
+            if quantized:
+                pool, dst[f"scl{i}"] = pool
+            full = vx.gather(spec, pool, table=row[None],
+                             scales=dst.get(f"scl{i}"), lead=lead,
+                             policy=pol)
+            dst[f"pos{i}"] = pool
+            k_all, v_all = vx.transpose(
+                vx.Segment(n=full.shape[-1], fields=2), full, policy=pol)
+            return attention.chunk_attention(
+                q, k_all, v_all, tpos, window=cfg.window_pattern[i])
+
+        def recur(i, step, h):
+            leaf = sb_c[f"pos{i}"]
+            st, _, y = _gated_scan(step, _slot_state(leaf), h, valid)
+            new_c[f"pos{i}"] = _put_slot(leaf, st)
+            return y
+
+        x, units = _superblock(cfg, ctx, sb_p, x, attend=attend,
+                               recur=recur, valid=valid, ffn_pol=pol)
         return (x, pools), (new_c, units)
 
     (_, pools), (new_blocks, units) = _scan_layers(
@@ -906,38 +845,52 @@ def paged_truncate(cfg: ModelConfig, cache: dict, new_pos) -> dict:
 
 def paged_verify_step(params, cache: dict, tokens: jax.Array,
                       cfg: ModelConfig, ctx, *, n_draft, active=None,
-                      fuse: bool | None = None, pool_shard=None):
-    """Speculative K-token verify over the paged cache.
+                      fuse: bool = True, pool_shard=None):
+    """One paged step of query width K over every slot: the decode step
+    at K = 1 (:func:`paged_decode_step`), the speculative verify above.
 
-    ``tokens`` is ``(B, K)`` int32 — column 0 is each slot's CURRENT
-    token (the last committed sample), columns 1..K-1 the draft model's
-    proposals.  ``n_draft`` (B,) in [1, K] is the number of REAL columns
-    per slot (traced: one jit trace serves every mixture of per-request
-    speculation widths, so `vx.PLANS` sees one spec).  Returns
-    ``(logits (B, K, V), out_tok (B, K), commit (B,), new_cache)``:
-    ``out_tok[:, j] = argmax(logits[:, j])`` and ``commit`` is the
-    greedy accept count — 1 (the token column 0 would have produced
-    anyway) plus the number of LEADING drafts that match the argmax of
-    the previous column.  The committed stream ``out_tok[b, :commit]``
-    is exactly the token stream the non-speculative greedy oracle
-    produces (K=1 degenerates to :func:`paged_decode_step` plus argmax).
+    ``tokens`` is ``(B, K)`` int32 with B = slots — column 0 is each
+    slot's CURRENT token (the last committed sample), columns 1..K-1 the
+    draft model's proposals.  ``n_draft`` (B,) in [1, K] is the number of
+    REAL columns per slot (traced: one jit trace serves every mixture of
+    per-request speculation widths, so `vx.PLANS` sees one spec).
+    ``active`` (B,) masks idle slots: they append nothing, advance
+    nothing and route to no expert (the scheduler's active-set
+    batching).  Returns ``(logits (B, K, V), out_tok (B, K), commit (B,),
+    new_cache)``: ``out_tok[:, j] = argmax(logits[:, j])`` and ``commit``
+    is the greedy accept count — 1 (the token column 0 would have
+    produced anyway) plus the number of LEADING drafts that match the
+    argmax of the previous column.  The committed stream
+    ``out_tok[b, :commit]`` is exactly the token stream the
+    non-speculative greedy oracle produces.
 
-    Access shape: the K draft positions stack along the BEAT axis of the
-    existing ``vx.Paged`` programs — the append flattens ``(B, K)`` rows
-    into ``(B*K,)`` scatter rows through the SAME paged-scatter arm the
-    chunked prefill uses (table rows repeated K times), and the read is
-    the SAME one fused page-gather + one fused FIELD=2 split as the
-    single-token step: one spec, one gather eqn, one pinned launch,
-    regardless of K.  Attention gathers PRE-append pages and the K fresh
-    beats are inserted as floats (a scatter, not a gather), so fused /
-    per-access / quantized arms all see bit-identical attention inputs,
-    and float pools match the single-token oracle bitwise.
+    Access shape: pages are allocated off the device free stack for
+    every page boundary ``[pos, pos + n_draft)`` crosses; the K positions
+    stack along the BEAT axis of one ``vx.Paged`` spec, and with
+    ``fuse=True`` ALL layers' page gathers run as ONE fused page-granular
+    program (the table encodes the heterogeneous per-slot lengths; the
+    compiled program is keyed only by page geometry) followed by ONE
+    fused FIELD=2 split — one gather eqn, one pinned launch, regardless
+    of K.  Attention reads those PRE-append pages and the K fresh beats
+    enter as floats by select, so fused, per-access and quantized arms
+    all see bit-identical attention inputs.  A pad query (a column past
+    ``n_draft``, or an idle slot) attends to the slot's pre-append
+    context; its output feeds nothing.  Sliding-window layers mask at
+    attention time instead of ring-overwriting.
 
-    Where the pool is written: as in :func:`paged_decode_step`, the fused
-    loop never reads the pool; each layer returns its ``B*K`` fresh
-    beats, and after the loop one page scatter per pool leaf writes them
-    all into the donated stack in place.  The ``fuse=False`` oracle scans
-    the stack one layer's pool at a time.
+    Where the pool is written: the fused loop never reads the pool; each
+    layer returns its ``B*K`` fresh beats as a small scan result, and
+    after the loop one page scatter per pool leaf writes every
+    superblock's beats into the donated stack in place, the superblock a
+    leading scatter coordinate (``-1`` and out-of-range rows drop; a pool
+    sharded on its page axis stays sharded).  The ``fuse=False`` oracle
+    scans the stack, one layer's pool at a time.  ``pool_shard`` (a
+    ``vx.Shard`` on the pool page axis, ``axis=-4``) lowers every page
+    gather shard-locally.  QUANTIZED pools (``scl{i}`` side tensors, see
+    :func:`init_paged_cache`) dequantize inside the same fused gather and
+    quantize the appended beats on write (the page scale widens
+    monotonically); the fresh beats are only quantized for the NEXT
+    step's read.
 
     Rejection rolls back through the page table ONLY: pages allocated
     this step at logical indices past the accept extent are guaranteed
@@ -963,7 +916,6 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
     if cfg.encoder is not None:
         raise NotImplementedError("paged serving covers decoder-only "
                                   "models; use encdec.decode_step")
-    fuse = cfg.step_fusion if fuse is None else fuse
     pol = cfg.vx_policy
     B, K = tokens.shape
     pos = cache["pos"]
@@ -977,6 +929,7 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
     ref = cache["ref"]
     quantized = _pool_quantized(cache, attn_pos)
     blocks_in = cache["blocks"]
+    # logical capacity; recurrent-only stacks carry O(1) state, no cap
     seq = n_seq * ps if attn_pos else (1 << 30)
     num_pages = free.shape[0] if attn_pos else 0
 
@@ -986,11 +939,14 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
     have = newp_grid = None
     spec = None
     if attn_pos:
-        # batched multi-page allocation: exactly the pages the oracle
-        # would allocate crossing boundaries in [pos, pos + n_draft - 1]
-        # (fresh pages start at a boundary >= pos, so a degraded missing
-        # mid-page tail is never re-allocated — oracle behavior).
-        # Exhaustion degrades locally, as in the single-token step.
+        # batched multi-page allocation: the pages crossing boundaries in
+        # [pos, pos + n_draft - 1] (fresh pages start at a boundary >=
+        # pos, so a degraded missing mid-page tail is never
+        # re-allocated).  An exhausted free stack degrades LOCALLY: pages
+        # whose pop rank exceeds the free count are not allocated (the
+        # table entry stays -1, their appends drop and their reads return
+        # zeros) — never an aliased page shared with a live slot, and
+        # free_top never goes negative.
         idx = jnp.arange(n_seq)[None, :]
         startp = (pos + ps - 1) // ps
         lastp = (pos + n_draft - 1) // ps
@@ -1007,6 +963,9 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
         ref = ref.at[jnp.where(have_f, newp_f, num_pages)].add(
             1, mode="drop")
         if quantized:
+            # freshly allocated pages start at scale 0 (see the chunk
+            # allocator): the widen-on-append then zeroes resident
+            # garbage and the first beat sets the true scale
             rst = jnp.where(have_f, newp_f, num_pages)
             blocks_in = dict(blocks_in)
             for i in attn_pos:
@@ -1014,18 +973,23 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
                     :, rst].set(0.0, mode="drop")
         spec = vx.Paged(page_size=ps, pages=n_seq, trail=2)
     # K-beat append plumbing: (B*K,) scatter rows through the table rows
-    # repeated K times — the chunked-prefill shape, one program per layer
+    # repeated K times; pad columns, idle slots and full sequences append
+    # nothing (dropped rows)
     wpos = jnp.where(valid & (tpos < seq), tpos, -1)       # (B, K)
     wpos_flat = wpos.reshape(-1)
     table_flat = jnp.repeat(table, K, axis=0) if attn_pos else None
-    qpos = jnp.where(valid, tpos, -1)                      # pad queries
-    b_idx = jnp.arange(B)[:, None]
-    wp_ins = jnp.where(valid & (tpos < seq), tpos, seq)    # drop pads
+    qpos = jnp.where(valid, tpos, pos[:, None] - 1)        # pad queries
 
-    x = layers.embed(tokens, params["embed"]).astype(cfg.cdtype)  # (B,K,d)
+    # the step's tokens as one flat (B*K, d) stream: at K = 1 every
+    # product is the dense decode step's (B, d) one
+    x = layers.embed(tokens.reshape(-1), params["embed"]).astype(cfg.cdtype)
 
     pre_split: dict[str, Any] = {}
     if fuse and attn_pos:
+        # ONE fused page gather for all layers' pools (stacked over
+        # superblocks AND over layers), then ONE fused FIELD=2 split.
+        # Quantized pools stack their scale tensors the same way — the
+        # dequant rides the same single program (zero extra launches).
         gathered = kv_interleaved.gather_paged_kv(
             [blocks_in[f"pos{i}"] for i in attn_pos], table, ps,
             policy=pol, shard=pool_shard,
@@ -1038,94 +1002,53 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
                 if fuse else pol)
     ffn_pol = pol.for_elems(B * K * 2 * cfg.d_ff) if fuse else pol
 
-    def _tok_scan_b(step_fn, state0, h, keep_dtype):
-        """Advance B slots' recurrent state over the K tokens, collecting
-        every intermediate state for the post-hoc accept-point select."""
-        def tok(st, inp):
-            ht, on = inp                             # ht (B, d), on (B,)
-            y, st2 = step_fn(ht, st)
-            st2 = _keep_active(st2, st, on)
-            return st2, (st2, jnp.where(on[:, None], y, 0.0))
-        _, (sts, ys) = jax.lax.scan(
-            tok, state0, (jnp.swapaxes(h, 0, 1), jnp.swapaxes(valid, 0, 1)))
-        return sts, jnp.swapaxes(ys, 0, 1).astype(keep_dtype)
-
     def sb_step(x, inp):
         sb_p, sb_c, sb_pre = inp
-        new_c, units = {}, {}
-        for i, kind in enumerate(cfg.block_pattern):
-            p = sb_p[f"pos{i}"]
-            if kind == "attn":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                q, k, v, kv = attention.qkv_project(
-                    p["attn"], h, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
-                    tpos, cfg.rope_theta, policy=beat_pol)
-                kv_flat = kv.reshape(B * K, cfg.n_kv_heads, 2 * cfg.hd)
-                if fuse:
-                    # the fresh beats, written into the stack after the
-                    # loop
-                    new_c[f"pos{i}"] = kv_flat
-                    k_pre, v_pre = sb_pre[f"pos{i}"]
-                else:
-                    pool = sb_c[f"pos{i}"]           # (P, ps, Kh, 2D)
-                    scl = sb_c.get(f"scl{i}")
-                    # per-access oracle reads PRE-append too (then the
-                    # fresh-beat insert below), so every arm sees
-                    # bit-identical attention inputs
-                    full = vx.gather(spec, pool, table=table, scales=scl,
-                                     policy=pol, shard=pool_shard)
-                    k_pre, v_pre = vx.transpose(
-                        vx.Segment(n=full.shape[-1], fields=2), full,
-                        policy=pol)
-                    pool = vx.scatter(spec, pool, kv_flat, table=table_flat,
-                                      pos=wpos_flat, scales=scl, policy=pol)
-                    if quantized:
-                        pool, new_c[f"scl{i}"] = pool
-                    new_c[f"pos{i}"] = pool
-                # insert ALL K fresh beats as floats (a scatter eqn —
-                # the gather gate stays at one); rows past n_draft drop
-                k_all = k_pre.at[b_idx, wp_ins].set(
-                    k.astype(k_pre.dtype), mode="drop")
-                v_all = v_pre.at[b_idx, wp_ins].set(
-                    v.astype(v_pre.dtype), mode="drop")
-                out = attention.chunk_attention(
-                    q, k_all, v_all, qpos, window=cfg.window_pattern[i])
-                x = x + (out.reshape(B, K, cfg.n_heads * cfg.hd)
-                         @ p["attn"]["wo"]).astype(x.dtype)
-            elif kind == "mamba":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                pm = dict(p["mamba"])
-                pm["in_proj"] = pm["in_proj"].reshape(cfg.d_model,
-                                                      2 * cfg.mamba.ed)
-                sts, y = _tok_scan_b(
-                    lambda ht, st: mamba_decode_step(pm, ht, st,
-                                                     cfg.mamba),
-                    sb_c[f"pos{i}"], h, x.dtype)
-                x = x + y
-                new_c[f"pos{i}"] = sts
-            elif kind == "mlstm":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                px = dict(p["xl"])
-                px["up"] = px["up"].reshape(cfg.d_model,
-                                            2 * cfg.xlstm.m_inner)
-                sts, y = _tok_scan_b(
-                    lambda ht, st: mlstm_decode_step(px, ht, st,
-                                                     cfg.xlstm),
-                    sb_c[f"pos{i}"], h, x.dtype)
-                x = x + y
-                new_c[f"pos{i}"] = sts
-            elif kind == "slstm":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                sts, y = _tok_scan_b(
-                    lambda ht, st: slstm_decode_step(p["slstm"], ht, st,
-                                                     cfg.xlstm),
-                    sb_c[f"pos{i}"], h, x.dtype)
-                x = x + y
-                new_c[f"pos{i}"] = sts
-            if cfg.pos_has_ffn(i) and cfg.moe_pattern[i]:
-                x, units[f"pos{i}"] = _moe_ffn(p, x, cfg, valid)
-            elif cfg.pos_has_ffn(i):
-                x, _ = _ffn_apply(p, x, cfg, ctx, i, policy=ffn_pol)
+        new_c = {}
+
+        def attend(i, pa, h):
+            q, k, v, kv = attention.qkv_project(
+                pa, h.reshape(B, K, -1), cfg.n_heads, cfg.n_kv_heads,
+                cfg.hd, tpos, cfg.rope_theta, policy=beat_pol)
+            kv_flat = kv.reshape(B * K, cfg.n_kv_heads, 2 * cfg.hd)
+            if fuse:
+                # the fresh beats, written into the stack after the loop
+                new_c[f"pos{i}"] = kv_flat
+                k_all, v_all = sb_pre[f"pos{i}"]
+            else:
+                pool = sb_c[f"pos{i}"]               # (P, ps, Kh, 2D)
+                scl = sb_c.get(f"scl{i}")
+                # the oracle reads PRE-append too
+                full = vx.gather(spec, pool, table=table, scales=scl,
+                                 policy=pol, shard=pool_shard)
+                k_all, v_all = vx.transpose(
+                    vx.Segment(n=full.shape[-1], fields=2), full,
+                    policy=pol)
+                pool = vx.scatter(spec, pool, kv_flat, table=table_flat,
+                                  pos=wpos_flat, scales=scl, policy=pol)
+                if quantized:
+                    pool, new_c[f"scl{i}"] = pool
+                new_c[f"pos{i}"] = pool
+            # the fresh beats enter by select, one static column at a
+            # time — elementwise, so the K selects fuse into one pass
+            j = jnp.arange(seq)[None, :]
+            for c in range(K):
+                ins = (valid[:, c, None]
+                       & (j == tpos[:, c, None]))[:, :, None, None]
+                k_all = jnp.where(ins, k[:, c:c + 1].astype(k_all.dtype),
+                                  k_all)
+                v_all = jnp.where(ins, v[:, c:c + 1].astype(v_all.dtype),
+                                  v_all)
+            return attention.chunk_attention(
+                q, k_all, v_all, qpos, window=cfg.window_pattern[i])
+
+        def recur(i, step, h):
+            _, new_c[f"pos{i}"], y = _gated_scan(
+                step, sb_c[f"pos{i}"], h.reshape(B, K, -1), valid)
+            return y.reshape(h.shape)
+
+        x, units = _superblock(cfg, ctx, sb_p, x, attend=attend,
+                               recur=recur, valid=valid, ffn_pol=ffn_pol)
         return x, (new_c, units)
 
     x, (new_blocks, units) = _scan_layers(
@@ -1135,20 +1058,18 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
     blocks = _write_beats(spec, dict(blocks_in, **new_blocks), beats,
                           table_flat, wpos_flat, pol)
 
-    x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = layers.rms_norm(x.reshape(B * K, -1), params["final_norm"],
+                        cfg.norm_eps)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    logits = layers.unembed(x, head.astype(cfg.cdtype))    # (B, K, V)
+    logits = layers.unembed(x, head.astype(cfg.cdtype)).reshape(B, K, -1)
 
     # greedy accept recurrence: column j's argmax is the oracle token at
     # position pos + j given columns 0..j were fed correctly; commit =
     # 1 + (# leading drafts matching the previous column's argmax)
     out_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    if K > 1:
-        match = ((tokens[:, 1:] == out_tok[:, :-1])
-                 & (jnp.arange(1, K)[None, :] < n_draft[:, None]))
-        m = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1)
-    else:
-        m = jnp.zeros((B,), jnp.int32)
+    match = ((tokens[:, 1:] == out_tok[:, :-1])
+             & (jnp.arange(1, K)[None, :] < n_draft[:, None]))
+    m = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1)
     commit = jnp.where(active, 1 + m, 0)
     new_pos = jnp.where(active, jnp.minimum(pos + commit, seq), pos)
 
@@ -1185,226 +1106,25 @@ def paged_verify_step(params, cache: dict, tokens: jax.Array,
 
 def paged_decode_step(params, cache: dict, token: jax.Array,
                       cfg: ModelConfig, ctx, *, active=None,
-                      fuse: bool | None = None,
+                      fuse: bool = True,
                       pool_shard=None) -> tuple[jax.Array, dict]:
-    """One decode step over the paged cache.  token: (B,) int32 with B =
-    slots.  Returns (logits (B, V), updated cache).
-
-    Differences from :func:`decode_step` (which remains the dense-cache
-    oracle): positions are PER-SLOT (``cache["pos"]``), the step takes an
-    ``active`` mask (idle slots append nothing, advance nothing and route
-    to no expert — the scheduler's active-set batching), appends allocate
-    a page off the device free stack when a slot crosses a page boundary,
-    and attention reads go through ``vx.Paged`` — with ``fuse=True`` ALL
-    layers' page gathers run as ONE fused page-granular program (the table
-    encodes the heterogeneous per-slot lengths; the compiled program is
-    keyed only by page geometry) followed by the usual ONE fused FIELD=2
-    split.
-    ``fuse=False`` is the per-access paged oracle.  Sliding-window layers
-    mask at attention time instead of ring-overwriting.
-
-    Where the pool is written: attention reads the pre-append pages from
-    that one gather plus the fresh beat, so the layer loop never reads the
-    pool.  Each layer returns its fresh beats ``(B, K, 2D)`` as a small
-    scan result, and after the loop ONE page scatter per pool leaf writes
-    every superblock's beats into the donated stack in place, the
-    superblock a leading scatter coordinate (``-1`` and out-of-range rows
-    drop; a pool sharded on its page axis stays sharded).  The
-    ``fuse=False`` oracle instead scans the stack, scattering and reading
-    back one layer's pool at a time.
-
-    ``pool_shard`` (a ``vx.Shard`` on the pool page axis, ``axis=-4``)
-    lowers every page gather shard-locally — the pool, sharded over the
-    mesh on its page axis, is never sliced globally (the PR 4 invariant
-    applied to the serving pool).
-
-    QUANTIZED pools (``scl{i}`` side tensors present, see
-    :func:`init_paged_cache`) dequantize inside the same fused gather
-    program, quantize the appended beat on write (page scale widens
-    monotonically), and attention always reads the pre-append pages
-    plus the fresh FLOAT beat — fused and per-access paths stay
-    bit-identical, and the beat is only quantized for the NEXT step's
-    read.
-    """
-    from repro.models.transformer import cast_params
-    params = cast_params(params, cfg)
-    if cfg.encoder is not None:
-        raise NotImplementedError("paged serving covers decoder-only "
-                                  "models; use encdec.decode_step")
-    fuse = cfg.step_fusion if fuse is None else fuse
-    pol = cfg.vx_policy
-    B = token.shape[0]
-    pos = cache["pos"]
-    if active is None:
-        active = jnp.ones((B,), bool)
-    else:
-        active = jnp.asarray(active, bool)
-    attn_pos, ps, n_seq = _paged_geometry(cfg, cache)
-    table, free, free_top = cache["table"], cache["free"], cache["free_top"]
-    ref = cache["ref"]
-    quantized = _pool_quantized(cache, attn_pos)
-    blocks_in = cache["blocks"]
-    # logical capacity; recurrent-only stacks carry O(1) state, no cap
-    seq = n_seq * ps if attn_pos else (1 << 30)
-
-    if attn_pos:
-        # allocate on page-boundary crossing — one shared table update for
-        # every layer (all layers append in lockstep).  An exhausted free
-        # stack degrades LOCALLY: slots whose pop rank exceeds the free
-        # count get no page (table entry stays -1, their appends drop and
-        # their reads return zeros) — never an aliased page shared with a
-        # live slot, and free_top never goes negative.
-        need = active & (pos % ps == 0) & (pos // ps < n_seq)
-        rank = jnp.cumsum(need.astype(jnp.int32)) - need
-        need = need & (rank < free_top)
-        newp = free[jnp.clip(free_top - 1 - rank, 0, free.shape[0] - 1)]
-        hit = need[:, None] & (jnp.arange(n_seq)[None, :]
-                               == (pos // ps)[:, None])
-        table = jnp.where(hit, newp[:, None], table)
-        free_top = free_top - jnp.sum(need.astype(jnp.int32))
-        ref = ref.at[jnp.where(need, newp, free.shape[0])].add(
-            1, mode="drop")
-        if quantized:
-            # freshly allocated pages start at scale 0 (see the chunk
-            # allocator): the widen-on-append then zeroes resident
-            # garbage and the first beat sets the true scale
-            rst = jnp.where(need, newp, free.shape[0])
-            blocks_in = dict(blocks_in)
-            for i in attn_pos:
-                blocks_in[f"scl{i}"] = blocks_in[f"scl{i}"].at[
-                    :, rst].set(0.0, mode="drop")
-    # idle slots and full sequences append nothing (dropped scatter rows)
-    write_pos = jnp.where(active & (pos < seq), pos, -1)
-    spec = (vx.Paged(page_size=ps, pages=n_seq, trail=2)
-            if attn_pos else None)
-
-    x = layers.embed(token, params["embed"]).astype(cfg.cdtype)
-
-    pre_split: dict[str, Any] = {}
-    if fuse and attn_pos:
-        # ONE fused page gather for all layers' pools (stacked over
-        # superblocks AND over layers), then ONE fused FIELD=2 split.
-        # Quantized pools stack their scale tensors the same way — the
-        # dequant rides the same single program (zero extra launches).
-        gathered = kv_interleaved.gather_paged_kv(
-            [blocks_in[f"pos{i}"] for i in attn_pos], table, ps,
-            policy=pol, shard=pool_shard,
-            scales=([blocks_in[f"scl{i}"] for i in attn_pos]
-                    if quantized else None))
-        splits = kv_interleaved.split_kv_step(gathered, policy=pol)
-        pre_split = {f"pos{i}": splits[a] for a, i in enumerate(attn_pos)}
-    scanned = _scanned_blocks(cache, blocks_in, attn_pos, fuse)
-    beat_pol = (pol.for_elems(B * cfg.n_kv_heads * 2 * cfg.hd)
-                if fuse else pol)
-    ffn_pol = pol.for_elems(B * 2 * cfg.d_ff) if fuse else pol
-    eff = (pos + active.astype(jnp.int32))[:, None]      # (B, 1) per slot
-
-    def sb_step(x, inp):
-        sb_p, sb_c, sb_pre = inp
-        new_c, units = {}, {}
-        for i, kind in enumerate(cfg.block_pattern):
-            p = sb_p[f"pos{i}"]
-            if kind == "attn":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                q, k, v, kv = attention.qkv_project(
-                    p["attn"], h[:, None], cfg.n_heads, cfg.n_kv_heads,
-                    cfg.hd, pos[:, None], cfg.rope_theta, policy=beat_pol)
-                if fuse:
-                    # the fresh beat, written into the stack after the loop
-                    new_c[f"pos{i}"] = kv[:, 0]
-                    pre = sb_pre[f"pos{i}"]
-                else:
-                    pool = sb_c[f"pos{i}"]             # (P, ps, K, 2D)
-                    scl = sb_c.get(f"scl{i}")
-                    if quantized:
-                        # per-access quantized arm reads PRE-append (like
-                        # the fused pre-gather) and inserts the fresh
-                        # FLOAT beat below — attention then sees
-                        # bit-identical inputs on both paths (the
-                        # appended beat is only quantized for the NEXT
-                        # step's read, exactly as in the fused arm)
-                        full = vx.gather(spec, pool, table=table,
-                                         scales=scl, policy=pol,
-                                         shard=pool_shard)
-                        pre = vx.transpose(
-                            vx.Segment(n=full.shape[-1], fields=2), full,
-                            policy=pol)
-                    pool = vx.scatter(spec, pool, kv[:, 0], table=table,
-                                      pos=write_pos, scales=scl, policy=pol)
-                    if quantized:
-                        pool, new_c[f"scl{i}"] = pool
-                    new_c[f"pos{i}"] = pool
-                if fuse or quantized:
-                    k_pre, v_pre = pre
-                    ins = (active[:, None]
-                           & (jnp.arange(seq)[None, :] == pos[:, None]))
-                    ins = ins[:, :, None, None]
-                    # k/v are (B, 1, K, D): broadcast over the seq axis
-                    k_all = jnp.where(ins, k.astype(k_pre.dtype), k_pre)
-                    v_all = jnp.where(ins, v.astype(v_pre.dtype), v_pre)
-                else:
-                    full = vx.gather(spec, pool, table=table, policy=pol,
-                                     shard=pool_shard)   # (B, S, K, 2D)
-                    k_all, v_all = vx.transpose(
-                        vx.Segment(n=full.shape[-1], fields=2), full,
-                        policy=pol)
-                out = attention.decode_attention(
-                    q[:, 0], k_all, v_all, eff,
-                    window=cfg.window_pattern[i])
-                x = x + (out.reshape(B, cfg.n_heads * cfg.hd)
-                         @ p["attn"]["wo"]).astype(x.dtype)
-            elif kind == "mamba":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                pm = dict(p["mamba"])
-                pm["in_proj"] = pm["in_proj"].reshape(cfg.d_model,
-                                                      2 * cfg.mamba.ed)
-                y, st = mamba_decode_step(pm, h, sb_c[f"pos{i}"], cfg.mamba)
-                x = x + jnp.where(active[:, None], y, 0)
-                new_c[f"pos{i}"] = _keep_active(st, sb_c[f"pos{i}"], active)
-            elif kind == "mlstm":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                px = dict(p["xl"])
-                px["up"] = px["up"].reshape(cfg.d_model,
-                                            2 * cfg.xlstm.m_inner)
-                y, st = mlstm_decode_step(px, h, sb_c[f"pos{i}"], cfg.xlstm)
-                x = x + jnp.where(active[:, None], y, 0)
-                new_c[f"pos{i}"] = _keep_active(st, sb_c[f"pos{i}"], active)
-            elif kind == "slstm":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                y, st = slstm_decode_step(p["slstm"], h, sb_c[f"pos{i}"],
-                                          cfg.xlstm)
-                x = x + jnp.where(active[:, None], y, 0)
-                new_c[f"pos{i}"] = _keep_active(st, sb_c[f"pos{i}"], active)
-            if cfg.pos_has_ffn(i) and cfg.moe_pattern[i]:
-                x, units[f"pos{i}"] = _moe_ffn(p, x, cfg, active)
-            elif cfg.pos_has_ffn(i):
-                x2, _ = _ffn_apply(p, x[:, None], cfg, ctx, i,
-                                   policy=ffn_pol)
-                x = x2[:, 0]
-        return x, (new_c, units)
-
-    x, (new_blocks, units) = _scan_layers(
-        cfg, sb_step, x, (params["blocks"], scanned, pre_split))
-    beats = ({f"pos{i}": new_blocks.pop(f"pos{i}") for i in attn_pos}
-             if fuse else {})
-    blocks = _write_beats(spec, dict(blocks_in, **new_blocks), beats,
-                          table, write_pos, pol)
-
-    x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    logits = layers.unembed(x, head.astype(cfg.cdtype))
-    new_pos = pos + (active & (pos < seq)).astype(jnp.int32)
-    return logits, _counted(dict(cache, pos=new_pos, table=table, free=free,
-                                 free_top=free_top, ref=ref,
-                                 blocks=blocks), units)
+    """One decode step over the paged cache: :func:`paged_verify_step` at
+    width 1.  token: (B,) int32 with B = slots.  Returns (logits (B, V),
+    updated cache).  :func:`decode_step` over the dense cache is its
+    bit-exact oracle for windowless attention stacks."""
+    logits, _, _, cache = paged_verify_step(
+        params, cache, token[:, None], cfg, ctx,
+        n_draft=jnp.ones(token.shape, jnp.int32), active=active, fuse=fuse,
+        pool_shard=pool_shard)
+    return logits[:, 0], cache
 
 
 def decode_step(params, cache: dict, token: jax.Array, cfg: ModelConfig,
-                ctx, *, fuse: bool | None = None,
+                ctx, *, fuse: bool = True,
                 kv_shard=None) -> tuple[jax.Array, dict]:
     """token: (B,) int32. Returns (logits (B, V), updated cache).
 
-    ``fuse`` (default cfg.step_fusion) enables whole-step access fusion:
+    ``fuse`` enables whole-step access fusion:
     the attention-time cache splits of EVERY layer — the step's dominant
     shift-routed traffic — are hoisted to the top of the step (they read
     the pre-append cache, which depends on nothing computed this step) and
@@ -1431,7 +1151,6 @@ def decode_step(params, cache: dict, token: jax.Array, cfg: ModelConfig,
     if cfg.encoder is not None:
         from repro.models import encdec
         return encdec.decode_step(params, cache, token, cfg, ctx)
-    fuse = cfg.step_fusion if fuse is None else fuse
     pol = cfg.vx_policy
     B = token.shape[0]
     pos = cache["len"]
@@ -1471,8 +1190,8 @@ def decode_step(params, cache: dict, token: jax.Array, cfg: ModelConfig,
         new_c = {}
         for i, kind in enumerate(cfg.block_pattern):
             p = sb_p[f"pos{i}"]
+            h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
             if kind == "attn":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
                 positions = jnp.broadcast_to(pos, (B, 1))
                 q, k, v, kv = attention.qkv_project(
                     p["attn"], h[:, None], cfg.n_heads, cfg.n_kv_heads,
@@ -1498,46 +1217,18 @@ def decode_step(params, cache: dict, token: jax.Array, cfg: ModelConfig,
                 x = x + (out.reshape(B, cfg.n_heads * cfg.hd)
                          @ p["attn"]["wo"]).astype(x.dtype)
                 new_c[f"pos{i}"] = kvc
-            elif kind == "mamba":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                pm = dict(p["mamba"])
-                pm["in_proj"] = pm["in_proj"].reshape(cfg.d_model,
-                                                      2 * cfg.mamba.ed)
-                y, st = mamba_decode_step(pm, h, sb_c[f"pos{i}"], cfg.mamba)
+            else:
+                y, new_c[f"pos{i}"] = _recurrent_step(kind, p, cfg)(
+                    h, sb_c[f"pos{i}"])
                 x = x + y
-                new_c[f"pos{i}"] = st
-            elif kind == "mlstm":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                px = dict(p["xl"])
-                px["up"] = px["up"].reshape(cfg.d_model,
-                                            2 * cfg.xlstm.m_inner)
-                y, st = mlstm_decode_step(px, h, sb_c[f"pos{i}"], cfg.xlstm)
-                x = x + y
-                new_c[f"pos{i}"] = st
-            elif kind == "slstm":
-                h = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
-                y, st = slstm_decode_step(p["slstm"], h, sb_c[f"pos{i}"],
-                                          cfg.xlstm)
-                x = x + y
-                new_c[f"pos{i}"] = st
             if cfg.pos_has_ffn(i):
                 x2, _ = _ffn_apply(p, x[:, None], cfg, ctx, i,
                                    policy=ffn_pol)
                 x = x2[:, 0]
         return x, new_c
 
-    if cfg.scan_layers:
-        x, new_blocks = jax.lax.scan(
-            sb_step, x, (params["blocks"], cache["blocks"], pre_split))
-    else:
-        outs = []
-        for sbi in range(cfg.n_superblocks):
-            sb = jax.tree.map(lambda a: a[sbi], params["blocks"])
-            cb = jax.tree.map(lambda a: a[sbi], cache["blocks"])
-            pb = jax.tree.map(lambda a: a[sbi], pre_split)
-            x, nb = sb_step(x, (sb, cb, pb))
-            outs.append(nb)
-        new_blocks = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+    x, new_blocks = _scan_layers(
+        cfg, sb_step, x, (params["blocks"], cache["blocks"], pre_split))
 
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]

@@ -71,7 +71,6 @@ class ModelConfig:
     # to vx.Policy.default() (REPRO_VX_IMPL env var, else platform) — ONE
     # knob for the whole stack (see repro/vx/policy.py).
     kernel_impl: str | None = None
-    step_fusion: bool = True               # whole-step access fusion (decode)
     ssm_chunk: int = 128
 
     @property

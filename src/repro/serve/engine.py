@@ -188,7 +188,7 @@ class BatchedServer:
 
     def __init__(self, cfg: ModelConfig, params, *, slots: int, max_len: int,
                  ctx: ShardCtx | None = None, cache_dtype=jnp.float32,
-                 fuse_step: bool = True, page_size: int | None = None,
+                 page_size: int | None = None,
                  num_pages: int | None = None, temperature: float = 0.0,
                  top_k: int | None = None, seed: int = 0, **lifecycle_kw):
         from repro.serve.scheduler import Scheduler
@@ -202,7 +202,7 @@ class BatchedServer:
         self.scheduler = Scheduler(
             cfg, params, slots=slots, max_len=max_len, page_size=page_size,
             num_pages=num_pages, cache_dtype=cache_dtype,
-            fuse_step=fuse_step, temperature=temperature, top_k=top_k,
+            temperature=temperature, top_k=top_k,
             seed=seed, **lifecycle_kw)
 
     @property

@@ -3,8 +3,8 @@
 The device state itself lives in the cache pytree built by
 ``models/decode.init_paged_cache`` (pos / table / free / free_top /
 blocks); this module wraps it with the HOST bookkeeping a scheduler needs
-— capacity checks before admission, page accounting, jit'd release /
-prefill-insert entry points — so `serve/scheduler.py` never touches the
+— capacity checks before admission, page accounting, jit'd release and
+prefix-sharing entry points — so `serve/scheduler.py` never touches the
 pytree layout directly.
 
 Memory model: attention layers share one pool of ``num_pages`` physical
@@ -18,8 +18,7 @@ tokens plus headroom (not the max_len worst case), so long-running
 decodes can still exhaust the stack mid-flight — when they do, the
 decode step degrades locally (the starved slot's appends drop, no page
 is ever aliased between slots) and the condition is observable as
-``free_pages() == 0``; ``insert_prefill`` refuses outright rather than
-starve a prompt.
+``free_pages() == 0``.
 
 PR 8 adds PREFIX SHARING on top of the same pool: pages carry a device
 refcount (``state["ref"]``), a slot can ADOPT another request's pages
@@ -34,8 +33,6 @@ of table entries referencing it plus the trie pin — via the
 ``external_ref`` provider hook the scheduler installs.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -96,17 +93,13 @@ class PagedCache:
         def deref_pages(c, ids):
             return dec.paged_deref_pages(cfg, c, ids)
 
-        # state donated on every mutation: release/insert return a full
-        # new pytree, and the pool is the big buffer — without donation
+        # state donated on every mutation: each returns a full new
+        # pytree, and the pool is the big buffer — without donation
         # each finish()/admission would pay a pool copy
         self._release = jax.jit(release_slot, donate_argnums=0)
-        # one jit entry per PADDED prompt length (a page multiple): the
-        # true length rides in as a traced operand, so mixed-length
-        # traffic costs at most pages_per_seq distinct traces
-        self._insert = {}
         # prefix-sharing entry points (PR 8): page-run adoption, CoW tail
         # fork, and the trie's external refcount pin — all donate the
-        # state like release/insert do
+        # state like release does
         self._adopt = jax.jit(adopt_prefix, donate_argnums=0)
         self._fork = jax.jit(fork_page, donate_argnums=0)
         self._addref = jax.jit(addref, donate_argnums=0)
@@ -191,25 +184,6 @@ class PagedCache:
     # -- mutation (jit'd, slot-traced: no retrace per slot) -----------------
     def release(self, slot: int) -> None:
         self.state = self._release(self.state, jnp.int32(slot))
-        self._maybe_check()
-
-    def insert_prefill(self, slot: int, cache_states, length: int,
-                       state_len: int | None = None) -> None:
-        """Embed prefill states (computed over ``state_len`` tokens —
-        defaults to ``length``) into the slot's pages."""
-        state_len = length if state_len is None else state_len
-        n_pg = self.pages_needed(state_len)
-        if self.free_pages() < n_pg:
-            raise RuntimeError(
-                f"page pool exhausted: prompt needs {n_pg} pages, "
-                f"{self.free_pages()} free")
-        fn = self._insert.get(state_len)
-        if fn is None:
-            fn = self._insert[state_len] = jax.jit(functools.partial(
-                dec.paged_insert_prefill, self.cfg, state_len=state_len),
-                donate_argnums=0)
-        self.state = fn(self.state, jnp.int32(slot), cache_states,
-                        jnp.int32(length))
         self._maybe_check()
 
     # -- prefix sharing (jit'd, fixed-width operands: no retrace) -----------

@@ -123,10 +123,7 @@ class Scheduler:
     per-page scales, dequant fused into the one page-gather program
     (models/decode.py), ~4x cache memory at bounded logit error;
     ``temperature`` / ``top_k`` /
-    ``seed`` configure sampling (greedy by default, deterministic);
-    ``prefill_pad`` pads prompts before prefill to bound jit retraces
-    (defaults to the page size, so prompt caches always land on whole
-    pages — a requirement of the paged insert).
+    ``seed`` configure sampling (greedy by default, deterministic).
 
     Lifecycle knobs: ``queue_depth`` bounds the admission queue
     (backpressure beyond it), ``preemption`` lets ``tick`` evict a
@@ -162,7 +159,7 @@ class Scheduler:
                  max_len: int, page_size: int | None = None,
                  num_pages: int | None = None, cache_dtype=jnp.float32,
                  kv_quant: str | None = None,
-                 fuse_step: bool = True, temperature: float = 0.0,
+                 temperature: float = 0.0,
                  top_k: int | None = None, seed: int = 0,
                  queue_depth: int | None = None, preemption: bool = True,
                  guard_nan: bool = False,
@@ -226,8 +223,7 @@ class Scheduler:
         # Each program is a named function, so that its XLA module reads
         # ``jit_<name>`` in a device trace.
         def decode_step(p, c, t, a):
-            return dec.paged_decode_step(p, c, t, cfg, None, active=a,
-                                         fuse=fuse_step)
+            return dec.paged_decode_step(p, c, t, cfg, None, active=a)
 
         def sample(logits, keys):
             return sample_tokens(logits, keys, temperature=temperature,
@@ -284,7 +280,7 @@ class Scheduler:
 
             def verify_step(p, c, t, n, a):
                 return dec.paged_verify_step(p, c, t, cfg, None, n_draft=n,
-                                             active=a, fuse=fuse_step)
+                                             active=a)
 
             def verify_finite(lg):
                 return jnp.all(jnp.isfinite(lg.astype(jnp.float32)),
@@ -292,7 +288,7 @@ class Scheduler:
 
             def draft_step(p, c, t, a):
                 return dec.paged_decode_step(p, c, t, draft_cfg, None,
-                                             active=a, fuse=fuse_step)
+                                             active=a)
 
             def draft_chunk(p, c, t, s, n):
                 return dec.paged_prefill_chunk(p, c, t, draft_cfg, None,

@@ -25,6 +25,7 @@ perfect drafts), and prefix sharing with the refcount audit on.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro import vx
 from repro.core import accessfuse
@@ -125,29 +126,35 @@ def _streams_equal(spec, oracle, steps):
 
 
 def test_k1_degenerates_to_single_step_bit_exact():
+    """The width-1 step is the paged decode step: fused, it reproduces
+    the ``fuse=False`` oracle bit for bit — logits, argmax tokens,
+    commits and every cache leaf — with an idle slot on some steps."""
     cfg = _cfg()
     params = init_params(cfg, jax.random.key(0))
     jd, jv = _jits(cfg)
+    jo = jax.jit(lambda p, c, t, n, a: dec.paged_verify_step(
+        p, c, t, cfg, None, n_draft=n, active=a, fuse=False))
     slots, ps, max_len = 2, 4, 32
     dc = dec.init_paged_cache(cfg, slots, max_len, ps, jnp.float32)
     vc = dec.init_paged_cache(cfg, slots, max_len, ps, jnp.float32)
-    act = jnp.ones((slots,), bool)
+    oc = dec.init_paged_cache(cfg, slots, max_len, ps, jnp.float32)
     nd = jnp.ones((slots,), jnp.int32)
     cur = (jnp.arange(slots, dtype=jnp.int32) * 7 + 3) % cfg.vocab
-    for _ in range(8):
+    for step in range(8):
+        act = jnp.asarray([True, step % 3 != 1])
         lg_d, dc = jd(params, dc, cur, act)
         lg_v, o, cm, vc = jv(params, vc, cur[:, None], nd, act)
-        # logits to reduction-order tolerance (the beat axis changes
-        # XLA's contraction order even at K=1); argmax tokens and cache
-        # positions are the bit-exact contract
-        np.testing.assert_allclose(np.asarray(lg_v[:, 0]),
-                                   np.asarray(lg_d), rtol=5e-4, atol=1e-5)
-        assert np.asarray(cm).tolist() == [1, 1]
-        nxt = jnp.argmax(lg_d, axis=-1).astype(jnp.int32)
-        np.testing.assert_array_equal(np.asarray(o[:, 0]), np.asarray(nxt))
-        np.testing.assert_array_equal(np.asarray(vc["pos"]),
-                                      np.asarray(dc["pos"]))
-        cur = nxt
+        lg_o, o_o, cm_o, oc = jo(params, oc, cur[:, None], nd, act)
+        np.testing.assert_array_equal(np.asarray(lg_v), np.asarray(lg_o))
+        np.testing.assert_array_equal(np.asarray(lg_v[:, 0]),
+                                      np.asarray(lg_d))
+        np.testing.assert_array_equal(np.asarray(o), np.asarray(o_o))
+        np.testing.assert_array_equal(np.asarray(cm), np.asarray(cm_o))
+        assert np.asarray(cm).tolist() == np.asarray(act, int).tolist()
+        for c in (vc, dc):
+            jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(b)), c, oc)
+        cur = jnp.where(act, o[:, 0], cur)
 
 
 def test_accept_all_catches_oracle_k_per_launch():
@@ -313,24 +320,45 @@ def _gate_cfg(impl):
                 name=f"spec-gate-{impl}")
 
 
-def test_verify_fuses_page_gathers_ref():
-    """Fusing the verify program removes the same three page-table
-    gathers as the single-token fused step on the 2sb x 2pos cfg —
-    K stacks along the beat axis of ONE vx.Paged spec, it does not
-    multiply gather programs."""
+def _scatter_operand_shapes(fn, *args) -> list[tuple]:
+    """Operand shapes of every `scatter` equation anywhere in the jaxpr."""
+    def rec(jaxpr):
+        out = []
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scatter":
+                out.append(tuple(eqn.invars[0].aval.shape))
+            for v in eqn.params.values():
+                for sub in accessfuse._child_jaxprs(v):
+                    out += rec(sub)
+        return out
+    return rec(jax.make_jaxpr(lambda *a: fn(*a))(*args).jaxpr)
+
+
+@pytest.mark.parametrize("width", (1, 4))
+def test_verify_fuses_page_gathers_ref(width):
+    """Fusing the width-K step removes the same three page-table gathers
+    at every width on the 2sb x 2pos cfg — K stacks along the beat axis
+    of ONE vx.Paged spec, it does not multiply gather programs — and the
+    fresh beats enter the pre-split K and V by select: no scatter writes
+    a (B, S, Kh, D) array."""
     cfg = _gate_cfg("ref")
     params = init_params(cfg, jax.random.key(0))
     cache = dec.init_paged_cache(cfg, 2, 64, 16, jnp.float32)
-    toks = jnp.zeros((2, 4), jnp.int32)
-    nd = jnp.full((2,), 4, jnp.int32)
-    gf = _count_gathers(lambda p, c, t, n: dec.paged_verify_step(
-        p, c, t, cfg, None, n_draft=n, fuse=True), params, cache, toks, nd)
-    gp = _count_gathers(lambda p, c, t, n: dec.paged_verify_step(
-        p, c, t, cfg, None, n_draft=n, fuse=False), params, cache, toks, nd)
+    toks = jnp.zeros((2, width), jnp.int32)
+    nd = jnp.full((2,), width, jnp.int32)
+
+    def step(fuse):
+        return lambda p, c, t, n: dec.paged_verify_step(
+            p, c, t, cfg, None, n_draft=n, fuse=fuse)
+    gf = _count_gathers(step(True), params, cache, toks, nd)
+    gp = _count_gathers(step(False), params, cache, toks, nd)
     # three page gathers fewer, and the in-place append looks its page ids
     # up once per leaf (one take per scatter), not once per leaf per
     # superblock
     assert gp - gf == 3 + 2, (gf, gp)
+    pre_split = (2, 64, cfg.n_kv_heads, cfg.hd)
+    shapes = _scatter_operand_shapes(step(True), params, cache, toks, nd)
+    assert pre_split not in shapes, shapes
 
 
 def test_verify_single_pinned_launch_pallas():
